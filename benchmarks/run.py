@@ -36,6 +36,10 @@ def main() -> None:
                     help="also write BENCH_*.json reports into OUT_DIR")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     def wanted(section: str) -> bool:
         return args.only is None or args.only == section
 

@@ -15,23 +15,25 @@ Five backends ship built-in:
 Backend choice is scoped, not global: ``use_backend("ref")`` binds a backend
 for the duration of a trace, and ``InferenceSession(..., backend=...)`` binds
 one per session, so a single process can serve fp32 on one session and
-int8-Pallas on another. The old ``REPRO_FORCE_KERNELS`` env toggle is only
-consulted once, when the process-wide *default* backend is first resolved —
-never in the hot path once a backend is bound.
+int8-Pallas on another. The process-wide default follows the platform:
+``pallas-tpu`` on a TPU, ``ref`` elsewhere; interpret-mode kernels are
+always an explicit choice (``use_backend("pallas-interpret")``).
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import os
 from typing import Dict, Iterator, List, Optional, Union
 
 import jax
 
-# NOTE: only the pure-jnp ref module is imported eagerly. The Pallas kernel
-# modules import jax.experimental.pallas at module load, which older/minimal
-# jax builds may lack — PallasBackend defers them to first use so plain fp32
-# serving never requires them (kernels stay optional).
+from repro.kernels import autotune as _at
+from repro.kernels import dynquant as _dyn
+from repro.kernels import flash_prefill as _fp
+from repro.kernels import paged_attn as _pa
+from repro.kernels import qdecode as _qd
+from repro.kernels import qmatmul as _static
+from repro.kernels import quantize as _quant
 from repro.kernels import ref as _ref
 
 
@@ -124,45 +126,31 @@ class PallasBackend(Backend):
         self.interpret = interpret
 
     def qmatmul_static(self, x, w_int8, w_scale, act_scale):
-        from repro.kernels import qmatmul as _static
-
         return _static.qmatmul_static(x, w_int8, w_scale, act_scale,
                                       interpret=self.interpret)
 
     def qmatmul_dynamic(self, x, w_int8, w_scale):
-        from repro.kernels import dynquant as _dyn
-
         return _dyn.qmatmul_dynamic(x, w_int8, w_scale,
                                     interpret=self.interpret)
 
     def quantize_weights(self, w):
-        from repro.kernels import quantize as _quant
-
         return _quant.quantize_weights(w, interpret=self.interpret)
 
     def qdecode(self, q, k_i8, k_s, v_i8, v_s, bias):
-        from repro.kernels import qdecode as _qd
-
         return _qd.qdecode_attention(q, k_i8, k_s, v_i8, v_s, bias,
                                      interpret=self.interpret)
 
     def paged_decode(self, q, k_pool, v_pool, tables, pos):
-        from repro.kernels import paged_attn as _pa
-
         return _pa.paged_decode_attention(q, k_pool, v_pool, tables, pos,
                                           interpret=self.interpret)
 
     def paged_qdecode(self, q, k_pool, k_scale, v_pool, v_scale, tables, pos):
-        from repro.kernels import paged_attn as _pa
-
         return _pa.paged_qdecode_attention(q, k_pool, k_scale, v_pool,
                                            v_scale, tables, pos,
                                            interpret=self.interpret)
 
     def paged_q4decode(self, q, k_pool, k_scale, v_pool, v_scale, tables,
                        pos):
-        from repro.kernels import paged_attn as _pa
-
         return _pa.paged_q4decode_attention(q, k_pool, k_scale, v_pool,
                                             v_scale, tables, pos,
                                             interpret=self.interpret)
@@ -170,18 +158,12 @@ class PallasBackend(Backend):
     def flash_prefill(self, q, k, v):
         # block shapes come from the deterministic autotuner (winner table
         # keyed per backend/head-dim/precision/seq bucket; REPRO_TILE_* pins)
-        from repro.kernels import autotune as _at
-        from repro.kernels import flash_prefill as _fp
-
         bq, bk = _at.tile_config(self.name, "flash_prefill", q.shape[-1],
                                  "fp32", q.shape[1])
         return _fp.flash_prefill_attention(q, k, v, block_q=bq, block_k=bk,
                                            interpret=self.interpret)
 
     def flash_qprefill(self, q, k_i8, k_s, v_i8, v_s):
-        from repro.kernels import autotune as _at
-        from repro.kernels import flash_prefill as _fp
-
         bq, bk = _at.tile_config(self.name, "flash_qprefill", q.shape[-1],
                                  "int8", q.shape[1])
         return _fp.flash_qprefill_attention(q, k_i8, k_s, v_i8, v_s,
@@ -189,9 +171,6 @@ class PallasBackend(Backend):
                                             interpret=self.interpret)
 
     def flash_q4prefill(self, q, k_i4, k_s, v_i4, v_s):
-        from repro.kernels import autotune as _at
-        from repro.kernels import flash_prefill as _fp
-
         bq, bk = _at.tile_config(self.name, "flash_q4prefill", q.shape[-1],
                                  "int4", q.shape[1])
         return _fp.flash_q4prefill_attention(q, k_i4, k_s, v_i4, v_s,
@@ -301,16 +280,11 @@ _ACTIVE: contextvars.ContextVar = contextvars.ContextVar(
 
 
 def default_backend() -> Backend:
-    """TPU -> native Pallas; CPU -> ref (XLA-fast), unless the legacy
-    REPRO_FORCE_KERNELS=1 toggle asks for interpret-mode kernels. The env
-    var is read once here, then cached."""
+    """TPU -> native Pallas; anything else -> ref (XLA-fast). Resolved
+    once, then cached."""
     if _DEFAULT[0] is None:
-        if jax.default_backend() == "tpu":
-            _DEFAULT[0] = get_backend("pallas-tpu")
-        elif os.environ.get("REPRO_FORCE_KERNELS", "0") == "1":
-            _DEFAULT[0] = get_backend("pallas-interpret")
-        else:
-            _DEFAULT[0] = get_backend("ref")
+        _DEFAULT[0] = get_backend("pallas-tpu" if jax.default_backend()
+                                  == "tpu" else "ref")
     return _DEFAULT[0]
 
 

@@ -28,6 +28,9 @@ def _kernel(x_ref, w_ref, wscale_ref, o_ref):
         xq, w_ref[...],
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
+        # an int8 dot is exact; an f32 contract precision in scope (e.g.
+        # default_matmul_precision("highest")) makes Mosaic refuse it
+        precision=jax.lax.Precision.DEFAULT,
     )
     o_ref[...] = acc.astype(jnp.float32) * a_scale * wscale_ref[...].astype(jnp.float32)
 

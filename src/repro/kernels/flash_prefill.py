@@ -31,12 +31,16 @@ Shapes (model layout in, model layout out):
     v            [B, S, Hkv, dv]
     out          [B, S, Hq, dv]      f32
 
+Scales travel as ``[B, Hkv, S, 1]`` columns (int8) and ``[B, Hkv, S,
+n_groups]`` f32 (int4), so every block's last two dims are whole or
+8-aligned, as the TPU compiler requires; the int4 kernel contracts the
+even/odd head_dim halves of q (``quantize.unpack_int4_halves``).
+
 Interpret-mode note: the Pallas interpreter executes grid steps in Python,
-so long prompts (the serving path this kernel exists for) would be timed at
-interpreter speed. Above ``INTERPRET_MAX_SEQ`` the interpret backend routes
-to the XLA-compiled tiled oracle in ``kernels.ref`` — identical tiling and
-accumulation order, same causal tile skip — keeping the timed path honest
-(same precedent as ``_use_kernels`` in ``kernels.ops``).
+so long prompts would run at interpreter speed. With ``interpret=True`` and
+more than ``INTERPRET_MAX_SEQ`` tokens the call routes to the XLA-compiled
+tiled oracle in ``kernels.ref`` (identical tiling and accumulation order,
+same causal tile skip). Compiled for the chip the kernel always runs.
 """
 from __future__ import annotations
 
@@ -47,13 +51,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quantize import dequantize_kv_int4
+from repro.kernels import online_softmax as osm
+from repro.kernels.quantize import (interleave_halves, split_halves,
+                                    unpack_int4_halves)
 
-NEG_INF = -2.0e38
-RUN_INIT = -1.0e30          # running-max seed (fits f32 after subtraction)
-
-# interpret mode runs grid steps in Python — beyond this length route to
-# the XLA tiled oracle so benches time compiled code, not the interpreter
+# interpret mode runs grid steps in Python — beyond this length it routes
+# to the XLA tiled oracle
 INTERPRET_MAX_SEQ = 256
 
 DEFAULT_BLOCK_Q = 128
@@ -68,114 +71,64 @@ def _positions(qi, ki, g, bq, bk, rows):
     return q_pos, k_pos
 
 
-def _accumulate(scores, v, o_ref, acc_ref, m_ref, l_ref, ki, last):
-    """One online-softmax step: scores [rows, bk] (masked), v [bk, dv]."""
-    m_prev = m_ref[...]                                    # [rows, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)                        # [rows, 1]
-    p = jnp.exp(scores - m_new)                            # [rows, bk]
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(ki == last)
-    def _finish():
-        o_ref[0, 0] = acc_ref[...] / l_ref[...]
+def _fp_tile(qs, kv_refs):
+    """(scores [rows, bk], value panels) of one tile pair for fp K/V."""
+    (q,), (k_ref, v_ref) = qs, kv_refs
+    scores = osm.dot_nt(q, k_ref[0, 0].astype(jnp.float32))
+    return scores, [v_ref[0, 0].astype(jnp.float32)]
 
 
-def _fp_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-               *, g, bq, bk, s, nk):
+def _q_tile(qs, kv_refs):
+    # int8 payloads dequantize per row against [bk, 1] scale columns —
+    # the ``payload * scale`` products of the oracle
+    (q,), (k_ref, ks_ref, v_ref, vs_ref) = qs, kv_refs
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+    return osm.dot_nt(q, k), [v]
+
+
+def _q4_tile(qs, kv_refs):
+    # unpack nibbles + per-group dequant in VMEM; only the packed bytes and
+    # the [bk, n_groups] scales crossed HBM
+    (q_even, q_odd), (k_ref, ks_ref, v_ref, vs_ref) = qs, kv_refs
+    k_even, k_odd = unpack_int4_halves(k_ref[0, 0], ks_ref[0, 0])
+    v_even, v_odd = unpack_int4_halves(v_ref[0, 0], vs_ref[0, 0])
+    scores = osm.dot_nt(q_even, k_even) + osm.dot_nt(q_odd, k_odd)
+    return scores, [v_even, v_odd]
+
+
+def _kernel(tile_fn, n_q, hd, *refs, g, bq, bk, s, nk):
+    """refs: n_q query parts, the K/V (+ scale) tiles, n_q outputs, n_q
+    accumulators, running max, normalizer."""
+    n_kv = len(refs) - 3 * n_q - 2
+    q_refs, kv_refs = refs[:n_q], refs[n_q:n_q + n_kv]
+    o_refs = refs[n_q + n_kv:2 * n_q + n_kv]
+    acc_refs, (m_ref, l_ref) = refs[2 * n_q + n_kv:-2], refs[-2:]
     qi, ki = pl.program_id(2), pl.program_id(3)
-    rows = q_ref.shape[2]
+    rows = q_refs[0].shape[2]
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, RUN_INIT)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        osm.init_state(acc_refs, m_ref, l_ref)
 
     q_last = qi * bq + bq - 1          # last query position in this tile
     last = jnp.minimum(nk - 1, q_last // bk)
 
     @pl.when(ki * bk <= q_last)        # causal: skip tiles above diagonal
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                # [rows, hd]
-        k = k_ref[0, 0].astype(jnp.float32)                # [bk, hd]
-        v = v_ref[0, 0].astype(jnp.float32)                # [bk, dv]
-        hd = q.shape[-1]
-        scores = jax.lax.dot_general(                      # [rows, bk]
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
+        scores, vals = tile_fn([r[0, 0].astype(jnp.float32) for r in q_refs],
+                               kv_refs)
+        scores = scores / jnp.sqrt(jnp.float32(hd))
         q_pos, k_pos = _positions(qi, ki, g, bq, bk, rows)
-        scores = jnp.where((k_pos <= q_pos) & (k_pos < s), scores, NEG_INF)
-        _accumulate(scores, v, o_ref, acc_ref, m_ref, l_ref, ki, last)
+        scores = jnp.where((k_pos <= q_pos) & (k_pos < s), scores,
+                           osm.NEG_INF)
+        osm.step(..., scores, vals, acc_refs, m_ref, l_ref)
 
-
-def _q_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-              acc_ref, m_ref, l_ref, *, g, bq, bk, s, nk):
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    rows = q_ref.shape[2]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, RUN_INIT)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q_last = qi * bq + bq - 1
-    last = jnp.minimum(nk - 1, q_last // bk)
-
-    @pl.when(ki * bk <= q_last)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)                # int8 -> f32
-        ks = ks_ref[0, 0]                                  # [bk]
-        v = v_ref[0, 0].astype(jnp.float32)
-        vs = vs_ref[0, 0]
-        hd = q.shape[-1]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        scores = scores * ks[None, :] / jnp.sqrt(hd).astype(jnp.float32)
-        q_pos, k_pos = _positions(qi, ki, g, bq, bk, rows)
-        scores = jnp.where((k_pos <= q_pos) & (k_pos < s), scores, NEG_INF)
-        # fold v scales into v — same products/order as scaling p, so the
-        # accumulator is shared with fp (paged_attn precedent)
-        _accumulate(scores, v * vs[:, None], o_ref, acc_ref, m_ref, l_ref,
-                    ki, last)
-
-
-def _q4_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
-               acc_ref, m_ref, l_ref, *, g, bq, bk, s, nk):
-    qi, ki = pl.program_id(2), pl.program_id(3)
-    rows = q_ref.shape[2]
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, RUN_INIT)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q_last = qi * bq + bq - 1
-    last = jnp.minimum(nk - 1, q_last // bk)
-
-    @pl.when(ki * bk <= q_last)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        # unpack nibbles + per-group dequant in VMEM; only the packed bytes
-        # and the [bk, n_groups] scales crossed HBM
-        k = dequantize_kv_int4(k_ref[0, 0], ks_ref[0, 0])     # [bk, hd]
-        v = dequantize_kv_int4(v_ref[0, 0], vs_ref[0, 0])     # [bk, dv]
-        hd = q.shape[-1]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(hd).astype(jnp.float32)
-        q_pos, k_pos = _positions(qi, ki, g, bq, bk, rows)
-        scores = jnp.where((k_pos <= q_pos) & (k_pos < s), scores, NEG_INF)
-        _accumulate(scores, v, o_ref, acc_ref, m_ref, l_ref, ki, last)
+        @pl.when(ki == last)
+        def _finish():
+            for o_ref, acc_ref in zip(o_refs, acc_refs):
+                o_ref[0, 0] = osm.normalize(acc_ref[...],
+                                                l_ref[...])
 
 
 def _pad_seq(x, target):
@@ -187,13 +140,15 @@ def _pad_seq(x, target):
 
 
 def _split_heads(q, k_like, hkv):
-    """Model layout -> kernel layout: q rows group-flattened per kv head."""
+    """Model layout -> kernel layout: q rows group-flattened per kv head;
+    K/V ``[B, S, Hkv, w]`` -> ``[B, Hkv, S, w]``, per-position scales
+    ``[B, S, Hkv]`` -> ``[B, Hkv, S, 1]`` columns."""
     b, sq, hq, hd = q.shape
     g = hq // hkv
     qr = q.reshape(b, sq, hkv, g, hd).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(b, hkv, sq * g, hd)
     return qr, [t.transpose(0, 2, 1, 3) if t.ndim == 4
-                else t.transpose(0, 2, 1) for t in k_like]
+                else t.transpose(0, 2, 1)[..., None] for t in k_like]
 
 
 def _merge_heads(out, b, sq, hkv, g, dv, s):
@@ -207,36 +162,44 @@ def _clip_blocks(s, block_q, block_k):
     return bq, bk
 
 
-def _call(kernel, q, kv_and_specs, *, b, hkv, g, bq, bk, nq, nk, dv,
-          interpret):
-    rows = bq * g
-    arrays, in_specs = zip(*kv_and_specs)
+def _flash(tile_fn, q, kv, *, dv, block_q, block_k, interpret):
+    """Pad to whole tiles, lay out per kv head, run ``tile_fn``'s kernel and
+    return the model layout. ``kv`` holds ``[B, S, Hkv, ...]`` payloads and
+    scales; int4 (``tile_fn is _q4_tile``) splits q into head_dim halves."""
+    b, s, hq, hd = q.shape
+    hkv = kv[0].shape[2]
+    g = hq // hkv
+    bq, bk = _clip_blocks(s, block_q, block_k)
+    nq, nk = -(-s // bq), -(-s // bk)
+    qr, kvr = _split_heads(_pad_seq(q, nq * bq),
+                           [_pad_seq(t, nk * bk) for t in kv], hkv)
+    qs = list(split_halves(qr)) if tile_fn is _q4_tile else [qr]
+    rows, w = bq * g, dv // len(qs)
+    row_spec = pl.BlockSpec((1, 1, rows, w),
+                            lambda b_, h, qi, ki: (b_, h, qi, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
         grid=(b, hkv, nq, nk),
-        in_specs=[pl.BlockSpec((1, 1, rows, q.shape[-1]),
-                               lambda b_, h, qi, ki: (b_, h, qi, 0)),
-                  *in_specs],
-        out_specs=pl.BlockSpec((1, 1, rows, dv),
-                               lambda b_, h, qi, ki: (b_, h, qi, 0)),
-        scratch_shapes=[pltpu.VMEM((rows, dv), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, 1), jnp.float32)],
+        in_specs=[pl.BlockSpec((1, 1, rows, t.shape[-1]),
+                               lambda b_, h, qi, ki: (b_, h, qi, 0))
+                  for t in qs]
+        + [pl.BlockSpec((1, 1, bk, t.shape[-1]),
+                        lambda b_, h, qi, ki: (b_, h, ki, 0)) for t in kvr],
+        out_specs=[row_spec] * len(qs),
+        scratch_shapes=[pltpu.VMEM((rows, w), jnp.float32)] * len(qs)
+        + [pltpu.VMEM((rows, 1), jnp.float32)] * 2,
     )
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, tile_fn, len(qs), hd,
+                               g=g, bq=bq, bk=bk, s=s, nk=nk)
+    outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, nq * rows, dv), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((b, hkv, nq * rows, w),
+                                        jnp.float32)] * len(qs),
         interpret=interpret,
-    )(q, *arrays)
-
-
-def _kv_spec(bk, width):
-    return pl.BlockSpec((1, 1, bk, width), lambda b, h, qi, ki: (b, h, ki, 0))
-
-
-def _kscale_spec(bk):
-    return pl.BlockSpec((1, 1, bk), lambda b, h, qi, ki: (b, h, ki))
+    )(*qs, *kvr)
+    out = interleave_halves(*outs) if len(outs) == 2 else outs[0]
+    return _merge_heads(out, b, nq * bq, hkv, g, dv, s)
 
 
 @functools.partial(jax.jit,
@@ -244,22 +207,11 @@ def _kscale_spec(bk):
 def flash_prefill_attention(q, k, v, *, block_q=None, block_k=None,
                             interpret: bool = False):
     """fp32/bf16 fused flash prefill — see module docstring for shapes."""
-    b, s, hq, hd = q.shape
-    hkv, dv = k.shape[2], v.shape[3]
-    if interpret and s > INTERPRET_MAX_SEQ:
+    if interpret and q.shape[1] > INTERPRET_MAX_SEQ:
         from repro.kernels import ref as _ref
         return _ref.flash_prefill_ref(q, k, v)
-    g = hq // hkv
-    bq, bk = _clip_blocks(s, block_q, block_k)
-    nq, nk = -(-s // bq), -(-s // bk)
-    qr, (kr, vr) = _split_heads(_pad_seq(q, nq * bq),
-                                [_pad_seq(k, nk * bk), _pad_seq(v, nk * bk)],
-                                hkv)
-    kernel = functools.partial(_fp_kernel, g=g, bq=bq, bk=bk, s=s, nk=nk)
-    out = _call(kernel, qr, [(kr, _kv_spec(bk, hd)), (vr, _kv_spec(bk, dv))],
-                b=b, hkv=hkv, g=g, bq=bq, bk=bk, nq=nq, nk=nk, dv=dv,
-                interpret=interpret)
-    return _merge_heads(out, b, nq * bq, hkv, g, dv, s)
+    return _flash(_fp_tile, q, [k, v], dv=v.shape[3], block_q=block_q,
+                  block_k=block_k, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -268,26 +220,12 @@ def flash_qprefill_attention(q, k_i8, k_scale, v_i8, v_scale, *,
                              block_q=None, block_k=None,
                              interpret: bool = False):
     """int8-KV fused-dequant flash prefill."""
-    b, s, hq, hd = q.shape
-    hkv, dv = k_i8.shape[2], v_i8.shape[3]
-    if interpret and s > INTERPRET_MAX_SEQ:
+    if interpret and q.shape[1] > INTERPRET_MAX_SEQ:
         from repro.kernels import ref as _ref
         return _ref.flash_qprefill_ref(q, k_i8, k_scale, v_i8, v_scale)
-    g = hq // hkv
-    bq, bk = _clip_blocks(s, block_q, block_k)
-    nq, nk = -(-s // bq), -(-s // bk)
-    sk = nk * bk
-    qr, (kr, ksr, vr, vsr) = _split_heads(
-        _pad_seq(q, nq * bq),
-        [_pad_seq(k_i8, sk), _pad_seq(k_scale, sk),
-         _pad_seq(v_i8, sk), _pad_seq(v_scale, sk)], hkv)
-    kernel = functools.partial(_q_kernel, g=g, bq=bq, bk=bk, s=s, nk=nk)
-    out = _call(kernel, qr,
-                [(kr, _kv_spec(bk, hd)), (ksr, _kscale_spec(bk)),
-                 (vr, _kv_spec(bk, dv)), (vsr, _kscale_spec(bk))],
-                b=b, hkv=hkv, g=g, bq=bq, bk=bk, nq=nq, nk=nk, dv=dv,
-                interpret=interpret)
-    return _merge_heads(out, b, nq * bq, hkv, g, dv, s)
+    return _flash(_q_tile, q, [k_i8, k_scale, v_i8, v_scale],
+                  dv=v_i8.shape[3], block_q=block_q, block_k=block_k,
+                  interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -296,25 +234,13 @@ def flash_q4prefill_attention(q, k_i4, k_scale, v_i4, v_scale, *,
                               block_q=None, block_k=None,
                               interpret: bool = False):
     """int4-KV fused-dequant flash prefill: packed payloads
-    [B, S, Hkv, hd // 2] + per-group scales [B, S, Hkv, hd // group]."""
-    b, s, hq, hd = q.shape
-    hkv, dv = k_i4.shape[2], v_i4.shape[3] * 2
-    if interpret and s > INTERPRET_MAX_SEQ:
+    [B, S, Hkv, hd // 2] + per-group scales [B, S, Hkv, hd // group] (f16
+    scales enter as f32: Mosaic has no f16 vector loads)."""
+    if interpret and q.shape[1] > INTERPRET_MAX_SEQ:
         from repro.kernels import ref as _ref
         return _ref.flash_q4prefill_ref(q, k_i4, k_scale, v_i4, v_scale)
-    g = hq // hkv
-    bq, bk = _clip_blocks(s, block_q, block_k)
-    nq, nk = -(-s // bq), -(-s // bk)
-    sk = nk * bk
-    ng = k_scale.shape[-1]
-    qr, (kr, ksr, vr, vsr) = _split_heads(
-        _pad_seq(q, nq * bq),
-        [_pad_seq(k_i4, sk), _pad_seq(k_scale, sk),
-         _pad_seq(v_i4, sk), _pad_seq(v_scale, sk)], hkv)
-    kernel = functools.partial(_q4_kernel, g=g, bq=bq, bk=bk, s=s, nk=nk)
-    out = _call(kernel, qr,
-                [(kr, _kv_spec(bk, hd // 2)), (ksr, _kv_spec(bk, ng)),
-                 (vr, _kv_spec(bk, dv // 2)), (vsr, _kv_spec(bk, ng))],
-                b=b, hkv=hkv, g=g, bq=bq, bk=bk, nq=nq, nk=nk, dv=dv,
-                interpret=interpret)
-    return _merge_heads(out, b, nq * bq, hkv, g, dv, s)
+    return _flash(_q4_tile, q,
+                  [k_i4, k_scale.astype(jnp.float32),
+                   v_i4, v_scale.astype(jnp.float32)],
+                  dv=v_i4.shape[3] * 2, block_q=block_q, block_k=block_k,
+                  interpret=interpret)

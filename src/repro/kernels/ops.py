@@ -1,36 +1,14 @@
 """Public entry points for the quantized compute primitives.
 
-These now delegate to the backend in scope via the pluggable registry in
+These delegate to the backend in scope via the pluggable registry in
 ``repro.api.backends`` (``ref`` / ``pallas-interpret`` / ``pallas-tpu``);
 ``repro.models.layers.linear`` calls them for quantized weight leaves, so a
-session traced under ``use_backend(...)`` bakes its backend in. The legacy
-``REPRO_FORCE_KERNELS=1`` env toggle is honoured once, when the process
-default backend is first resolved — not per call.
+session traced under ``use_backend(...)`` bakes its backend in.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-
-def _interpret() -> bool:
-    """Deprecated shim (pre-Backend-registry): whether Pallas kernels should
-    run in interpret mode on this host. Deliberately uncached so a runtime
-    backend change is never served a stale answer."""
-    return jax.default_backend() != "tpu"
-
-
-def _use_kernels() -> bool:
-    """Deprecated shim: Pallas interpret mode is Python-slow; inside large
-    traced models on CPU we route to the (identical-semantics) ref
-    implementation and keep kernel execution for the kernel tests / TPU.
-    Toggle with REPRO_FORCE_KERNELS=1. Superseded by
-    ``repro.api.backends`` — prefer ``use_backend("pallas-interpret")``."""
-    import os
-
-    if jax.default_backend() == "tpu":
-        return True
-    return os.environ.get("REPRO_FORCE_KERNELS", "0") == "1"
 
 
 def _backend():

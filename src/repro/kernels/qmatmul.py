@@ -30,6 +30,9 @@ def _kernel(x_ref, w_ref, wscale_ref, ascale_ref, o_ref, acc_ref, *, nk: int):
         xq, w_ref[...],
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
+        # an int8 dot is exact; an f32 contract precision in scope (e.g.
+        # default_matmul_precision("highest")) makes Mosaic refuse it
+        precision=jax.lax.Precision.DEFAULT,
     )
 
     @pl.when(pl.program_id(2) == nk - 1)

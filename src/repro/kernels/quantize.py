@@ -14,8 +14,9 @@ than int8's per-(slot, head) f32 scalar — f16 keeps the scale overhead at
 scale is an absmax/7 magnitude, far inside f16 range, and its <=2^-11
 relative error is noise next to the 4-bit step). ``pack_int4``/
 ``unpack_int4`` define the wire layout — element ``d`` lives in byte
-``d // 2``, even index in the low nibble — and the Pallas kernels replicate
-exactly this unpack in-VMEM.
+``d // 2``, even index in the low nibble — and the Pallas kernels read it
+in-VMEM through ``unpack_int4_halves``, which yields the same values split
+into even and odd elements.
 """
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 BN = 256
 
@@ -84,6 +86,40 @@ def dequantize_kv_int4(t_i4, t_s):
     return xg.reshape(x.shape)
 
 
+def unpack_int4_halves(packed, scale):
+    """Kernel-side dequant: (packed [..., hd//2] int8, scale [..., n_groups]
+    f32) -> (even, odd) f32 [..., hd//2], the dequantized elements
+    0, 2, 4, ... and 1, 3, 5, ... of each row.
+
+    Every value stays in the lane it was loaded in: Mosaic refuses both the
+    nibble interleave of ``unpack_int4`` and the ``[..., groups, g]``
+    reshape of ``dequantize_kv_int4``. Callers contract against the even and
+    odd halves of q (``split_halves``) and re-interleave what they
+    accumulate over values (``interleave_halves``)."""
+    p = packed.astype(jnp.int32)
+    lo = ((p << 28) >> 28).astype(jnp.float32)    # sign-extended low nibble
+    hi = ((p << 24) >> 28).astype(jnp.float32)
+    # packed lane j holds elements 2j and 2j+1, both in group j // (g // 2)
+    half_group = packed.shape[-1] // scale.shape[-1]
+    group = jax.lax.broadcasted_iota(jnp.int32, lo.shape, lo.ndim - 1) \
+        // half_group
+    s = scale[..., 0:1]
+    for i in range(1, scale.shape[-1]):
+        s = jnp.where(group == i, scale[..., i:i + 1], s)
+    return lo * s, hi * s
+
+
+def split_halves(x):
+    """[..., d] -> ([..., d//2] even elements, [..., d//2] odd elements)."""
+    return x[..., 0::2], x[..., 1::2]
+
+
+def interleave_halves(even, odd):
+    """Inverse of ``split_halves``."""
+    return jnp.stack([even, odd], axis=-1).reshape(
+        *even.shape[:-1], even.shape[-1] * 2)
+
+
 def _kernel(w_ref, q_ref, scale_ref):
     w = w_ref[...].astype(jnp.float32)                         # [K, bn]
     absmax = jnp.maximum(jnp.max(jnp.abs(w), axis=0, keepdims=True), 1e-12)
@@ -95,10 +131,6 @@ def _kernel(w_ref, q_ref, scale_ref):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quantize_weights(w, *, interpret: bool = False):
     """w [K, N] float -> (w_int8 [K, N], scale [1, N])."""
-    # deferred so the pure-jnp int4 helpers above stay importable (via
-    # kernels.ref) on jax builds without jax.experimental.pallas
-    from jax.experimental import pallas as pl
-
     k, n = w.shape
     bn = min(BN, n)
     np_ = -(-n // bn) * bn

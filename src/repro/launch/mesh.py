@@ -9,12 +9,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on make_mesh
-    from jax.sharding import AxisType
-except ImportError:  # 0.4.x pin of the CI matrix
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 #: the canonical hint for forcing a multi-device host platform in tests/CI
 HOST_DEVICES_FLAG = "XLA_FLAGS=--xla_force_host_platform_device_count"
@@ -40,11 +35,7 @@ def require_devices(n: int) -> None:
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    devs = np.array(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return Mesh(devs, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -63,15 +54,11 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0) -> Mesh:
 
 
 def make_tp_mesh(tp: int) -> Mesh:
-    """Serving tensor-parallel mesh: ("data", "model") with data=1.
-
-    Plain ``Mesh`` (no axis types): serving TP drives explicit shard_map
-    collectives, never GSPMD auto-sharding, and must build on the 0.4.x
-    CI pin too.
-    """
+    """Serving tensor-parallel mesh: ("data", "model") with data=1, over
+    the first ``tp`` devices in the order ``jax.make_mesh`` picks for the
+    chip topology."""
     _check_devices(tp, f"make_tp_mesh(tp={tp})")
-    devs = np.array(jax.devices()[:tp]).reshape(1, tp)
-    return Mesh(devs, ("data", "model"))
+    return _make_mesh((1, tp), ("data", "model"))
 
 
 # Hardware constants for the roofline report (TPU v5e)

@@ -26,6 +26,9 @@ def main():
                     help="PRNG seed for ad-hoc params and request payloads")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro import configs as C
     from repro.core.quant import QuantConfig, quantize_tree
     from repro.models import init_params

@@ -18,7 +18,7 @@ import re
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.models.config import ModelConfig
 
@@ -247,15 +247,14 @@ def tp_cache_specs(cfg: ModelConfig, caches, mesh: Mesh):
 
 
 def constrain(x: jax.Array, *entries) -> jax.Array:
-    """Sharding constraint that is a no-op outside a mesh context.
+    """Sharding constraint that is a no-op outside a mesh context and
+    inside a shard_map body (manual axes: the body holds its local shard).
 
     Entries use logical names: "batch" -> all non-model axes, "model".
     """
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty or not mesh.axis_names:
-            return x
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.axis_names or any(
+            t == AxisType.Manual for t in mesh.axis_types):
         return x
     resolved = []
     for e in entries:

@@ -40,22 +40,11 @@ from repro.models import transformer as _m
 from repro.models.config import ModelConfig
 from repro.models.sharding import (tp_cache_specs, tp_param_specs, tp_region)
 
-try:  # moved to jax.shard_map in newer releases
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - newer jax
-    _shard_map_impl = jax.shard_map
-
-
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: replication checking off (the "exact"
-    combine produces provably-replicated outputs the checker predates)."""
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    """shard_map with replication checking off (the "exact" combine produces
+    provably-replicated outputs the checker cannot see)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------------- #
@@ -115,16 +104,31 @@ def tp_local_config(cfg: ModelConfig, tp: int) -> ModelConfig:
 # --------------------------------------------------------------------- #
 # Host-side weight prep
 # --------------------------------------------------------------------- #
-def _wi_permutation(two_ff: int, tp: int) -> np.ndarray:
-    """Column order making each shard's fused gate|up slice locally
-    splittable: shard s gets [gate_s | up_s] instead of a naive contiguous
-    chunk (which would hand shard 0 all-gate and shard tp-1 all-up)."""
-    ff = two_ff // 2
-    c = ff // tp
-    return np.concatenate([
-        np.concatenate([np.arange(s * c, (s + 1) * c),
-                        ff + np.arange(s * c, (s + 1) * c)])
-        for s in range(tp)])
+def _wi_permuter(ndim: int, sharding, tp: int):
+    """Jitted column permutation of one ``wi`` leaf, making each shard's
+    fused gate|up slice locally splittable: shard s gets [gate_s | up_s]
+    instead of a naive contiguous chunk (which would hand shard 0 all-gate
+    and shard tp-1 all-up). Columns ``[gate | up]`` viewed as ``[2, tp,
+    ff / tp]`` become ``[tp, 2, ff / tp]``. On column-sharded weights that
+    is an all-to-all; it runs one layer at a time and keeps the leaf's
+    sharding, so no device ever holds more than its own slice plus one
+    layer's."""
+    inner = None
+    if isinstance(sharding, NamedSharding):
+        spec = tuple(sharding.spec) + (None,) * (ndim - len(sharding.spec))
+        inner = NamedSharding(sharding.mesh, P(*spec[-2:]))
+
+    def one(w):
+        d, two_ff = w.shape
+        w = w.reshape(d, 2, tp, two_ff // (2 * tp))
+        w = jnp.swapaxes(w, 1, 2).reshape(d, two_ff)
+        return w if inner is None else jax.lax.with_sharding_constraint(
+            w, inner)
+
+    def permute(wi):
+        return jax.lax.map(one, wi) if wi.ndim > 2 else one(wi)
+
+    return jax.jit(permute, out_shardings=sharding)
 
 
 def permute_wi_for_tp(params, tp: int):
@@ -136,8 +140,8 @@ def permute_wi_for_tp(params, tp: int):
     def rule(path, leaf):
         keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
         if len(keys) >= 2 and keys[-2] == "mlp" and keys[-1] == "wi":
-            idx = _wi_permutation(leaf.shape[-1], tp)
-            return leaf[..., idx]
+            return _wi_permuter(leaf.ndim, getattr(leaf, "sharding", None),
+                                tp)(leaf)
         return leaf
 
     return jax.tree_util.tree_map_with_path(rule, params)
@@ -174,14 +178,14 @@ class TPContext:
 
     # -------------------------- placement ------------------------------ #
     def shard_params(self, params):
-        """Permute fused-MLP columns, then place every leaf per its TP
-        spec (one transfer at engine init — the jitted entry points then
-        see already-resident shards)."""
-        params = permute_wi_for_tp(params, self.tp)
+        """Place every leaf per its TP spec (one transfer at engine init —
+        the jitted entry points then see already-resident shards; leaves
+        already placed so stay where they are), then permute the
+        fused-MLP columns on the mesh."""
         self._pspecs = tp_param_specs(params, self.mesh, self.combine)
         shardings = jax.tree.map(
             lambda s: NamedSharding(self.mesh, s), self._pspecs)
-        return jax.device_put(params, shardings)
+        return permute_wi_for_tp(jax.device_put(params, shardings), self.tp)
 
     def param_specs(self, params):
         if self._pspecs is None:

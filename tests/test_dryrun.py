@@ -7,12 +7,6 @@ import sys
 
 import pytest
 
-try:  # repro.launch.mesh/dryrun need jax >= 0.4.35 mesh axis types
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:
-    pytest.skip("jax.sharding.AxisType unavailable in this jax version",
-                allow_module_level=True)
-
 MINI = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

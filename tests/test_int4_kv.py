@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro import configs as C
 from repro.api import ModelArtifact
 from repro.kernels.quantize import (KV_GROUP, dequantize_kv_int4,

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro import configs as C
 from repro.models import init_params, prefill
 from repro.serving.kvcache import (BlockAllocator, PagedKVCache,

@@ -54,6 +54,23 @@ def test_paged_kernel_matches_ref_int8():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_paged_kernel_idle_slot_is_zero_not_nan():
+    """An idle slot (table all -1) masks every key: its output must be 0,
+    not 0/0. A NaN there reaches the trash block through the slot's K/V
+    write, and from there every live sequence with an unallocated table
+    entry (``0 * NaN`` in the value contraction)."""
+    q, k_pool, v_pool, tables, pos = _rand_case(seed=5)
+    tables = tables.at[1].set(-1)
+    got = np.asarray(paged_attn.paged_decode_attention(
+        q, k_pool, v_pool, tables, pos, interpret=True))
+    assert np.all(got[1] == 0.0)
+    live = np.array([0, 2])
+    want = ref.paged_decode_ref(q[live], k_pool, v_pool, tables[live],
+                                pos[live])
+    np.testing.assert_allclose(got[live], np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_paged_ref_matches_contiguous_qdecode():
     """Gathering pools through the table must equal the contiguous int8
     oracle on the hand-packed cache (per sequence)."""

@@ -1,12 +1,6 @@
 """Sharding rule units: divisibility guards, quantized-leaf handling, cache
 heuristics — all on an abstract mesh (no devices needed)."""
-import pytest
-
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # pre-0.4.35 jax: no AbstractMesh axis types
-    pytest.skip("jax.sharding.AxisType unavailable in this jax version",
-                allow_module_level=True)
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro import configs as C
 from repro.models.sharding import (cache_spec, checked_spec, data_spec,
