@@ -98,13 +98,12 @@ def hash_prompt_blocks(tokens: Sequence[int], block_size: int,
 
 @dataclasses.dataclass
 class AllocatorStats:
-    allocated: int = 0            # total successful alloc() calls
     evictions: int = 0            # cached blocks dropped for reuse
     cow_copies: int = 0           # copy-on-write block duplications
     peak_in_use: int = 0          # high-water mark of referenced blocks
 
     def reset(self) -> None:
-        self.allocated = self.evictions = self.cow_copies = 0
+        self.evictions = self.cow_copies = 0
         self.peak_in_use = 0
 
 
@@ -170,7 +169,6 @@ class BlockAllocator:
         else:
             return None
         self._ref[bid] = 1
-        self.stats.allocated += 1
         self.stats.peak_in_use = max(self.stats.peak_in_use, self.in_use)
         return bid
 

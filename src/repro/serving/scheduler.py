@@ -71,12 +71,14 @@ Deterministic and thread-free, like the rest of the serving layer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models import (decode_step, decode_step_paged, init_cache, prefill,
                           prefill_paged, verify_step, verify_step_paged)
@@ -114,6 +116,7 @@ METRIC_KEYS = (
     "spec_accepted_tokens",      # draft tokens accepted AND committed
     "acceptance_rate",           # accepted / proposed draft tokens
     "accepted_tokens_per_step",  # committed tokens per verify round (>1 good)
+    "host_syncs",                # device values read into Python
 )
 
 
@@ -138,6 +141,7 @@ class GenRequest:
     out_tokens: Optional[List[int]] = None
     done: bool = False
     submitted_at: float = 0.0
+    admitted_at: float = 0.0           # first took a slot (kept on resume)
     first_token_at: float = 0.0
     finished_at: float = 0.0
     # v2 fields
@@ -308,6 +312,7 @@ class ContinuousBatchingEngine:
         self.rejected_total = 0
         self.prefill_tokens = 0        # prompt tokens processed by prefill
         self.preempted_total = 0
+        self.host_syncs = 0            # device values read into Python
         self.cancelled_total = 0
         self.prefix_hit_tokens = 0
         self.prompt_tokens_computed = 0
@@ -362,10 +367,13 @@ class ContinuousBatchingEngine:
                 self.cache = self._tp_ctx.shard_cache(self.cache)
         # jit entry points (shapes fixed by the slot pool), traced with this
         # engine's backend in scope so the kernel choice is baked in;
-        # draft=True binds the draft model's backend instead
-        def bind(fn, *, draft=False, **jit_kw):
+        # draft=True binds the draft model's backend instead. ``name`` is
+        # the program's name in a profile (``jit_<name>``)
+        def bind(name, fn, *, draft=False, **jit_kw):
+            fn.__name__ = fn.__qualname__ = name
             jitted = jax.jit(fn, **jit_kw)
 
+            @functools.wraps(jitted)
             def call(*args):
                 with use_backend(self.draft_backend if draft
                                  else self.backend):
@@ -378,18 +386,20 @@ class ContinuousBatchingEngine:
         # call site below stays identical
         tpx = self._tp_ctx
         if tpx is not None:
-            self._decode = bind(lambda p, c, t, pos:
+            self._decode = bind("decode_step", lambda p, c, t, pos:
                                 tpx.decode_step(p, c, t, pos))
-            self._prefill = bind(lambda p, b, nv:
+            self._prefill = bind("prefill", lambda p, b, nv:
                                  tpx.prefill(p, b, nv, pad_to=self._pad_len))
         else:
             self._decode = bind(
+                "decode_step",
                 lambda p, c, t, pos: decode_step(p, c, t, pos, cfg))
             # ``nv`` (traced int32) marks the true token count: _admit_dense
             # bucket-pads the token axis (where bucketed_prefill_ok allows)
             # so distinct prompt lengths share one compiled prefill per
             # bucket
             self._prefill = bind(
+                "prefill",
                 lambda p, b, nv: prefill(p, b, cfg, pad_to=self._pad_len,
                                          n_valid=nv))
         if spec is not None:
@@ -402,25 +412,30 @@ class ContinuousBatchingEngine:
             self.draft_positions = jnp.zeros((n_slots,), jnp.int32)
             self._draft_trash = self._pad_len - 1
             self._draft_decode = bind(
+                "draft_decode_step",
                 lambda p, c, t, pos: decode_step(p, c, t, pos, dcfg),
                 draft=True)
             self._draft_prefill = bind(
+                "draft_prefill",
                 lambda p, b, nv: prefill(p, b, dcfg, pad_to=self._pad_len,
                                          n_valid=nv),
                 draft=True)
             if tpx is not None:
-                self._verify = bind(lambda p, c, t, pos:
+                self._verify = bind("verify_step", lambda p, c, t, pos:
                                     tpx.verify_step(p, c, t, pos))
             else:
                 self._verify = bind(
+                    "verify_step",
                     lambda p, c, t, pos: verify_step(p, c, t, pos, cfg))
             if paged:
                 if tpx is not None:
                     self._verify_paged = bind(
+                        "verify_step_paged",
                         lambda p, c, t, pos, tabs: tpx.verify_step_paged(
                             p, c, t, pos, tabs))
                 else:
                     self._verify_paged = bind(
+                        "verify_step_paged",
                         lambda p, c, t, pos, tabs: verify_step_paged(
                             p, c, t, pos, tabs, cfg))
         self.spec_events = 0           # per-slot verify acceptance rounds
@@ -430,13 +445,16 @@ class ContinuousBatchingEngine:
         if paged:
             if tpx is not None:
                 self._decode_paged = bind(
+                    "decode_step_paged",
                     lambda p, c, t, pos, tabs: tpx.decode_step_paged(
                         p, c, t, pos, tabs))
                 self._prefill_paged = bind(
+                    "prefill_paged",
                     lambda p, c, b, nv, tabs: tpx.prefill_paged(
                         p, c, b, nv, tabs))
             else:
                 self._decode_paged = bind(
+                    "decode_step_paged",
                     lambda p, c, t, pos, tabs: decode_step_paged(
                         p, c, t, pos, tabs, cfg))
                 # cold prefill scatters K/V straight into the block pools
@@ -445,6 +463,7 @@ class ContinuousBatchingEngine:
                 # compile per bucket instead of one per distinct prompt
                 # length
                 self._prefill_paged = bind(
+                    "prefill_paged",
                     lambda p, c, b, nv, tabs: prefill_paged(p, c, b, nv,
                                                             tabs, cfg))
 
@@ -482,6 +501,7 @@ class ContinuousBatchingEngine:
         self.prefill_tokens = 0
         self.rejected_total = 0
         self.preempted_total = 0
+        self.host_syncs = 0
         self.cancelled_total = 0
         self.prefix_hit_tokens = 0
         self.prompt_tokens_computed = 0
@@ -635,19 +655,21 @@ class ContinuousBatchingEngine:
     # ---------------------------------------------------------------- #
     def _admit(self) -> None:
         """Prefill the first chunk of pending requests into free slots."""
-        for slot in range(self.n_slots):
-            if self.active[slot] is not None:
-                continue
-            while self._pending and self._pending[0][2].status == "cancelled":
-                heapq.heappop(self._pending)     # lazily drop cancellations
-            if not self._pending:
-                continue
-            if self.paged:
-                if not self._admit_paged(slot):
-                    break        # pool cannot take the head request yet
-            else:
-                _, _, req = heapq.heappop(self._pending)
-                self._admit_dense(slot, req)
+        with TraceAnnotation("serving.admit"):
+            for slot in range(self.n_slots):
+                if self.active[slot] is not None:
+                    continue
+                while (self._pending
+                       and self._pending[0][2].status == "cancelled"):
+                    heapq.heappop(self._pending)  # lazily drop cancellations
+                if not self._pending:
+                    continue
+                if self.paged:
+                    if not self._admit_paged(slot):
+                        break    # pool cannot take the head request yet
+                else:
+                    _, _, req = heapq.heappop(self._pending)
+                    self._admit_dense(slot, req)
 
     def _pad_tokens(self, batch: dict, cfg: ModelConfig, total: int) -> dict:
         """Bucket-pad the token axis so every prompt length in a power-of-
@@ -665,16 +687,18 @@ class ContinuousBatchingEngine:
         return batch
 
     def _admit_dense(self, slot: int, req: GenRequest) -> None:
+        self._stamp_admitted(req)
         s = req.prompt_len
         chunk = min(self.prefill_chunk, s) if self.prefill_chunk else s
-        batch = {"tokens": req.tokens[:, :chunk]}
-        if req.frontend_embeds is not None:
-            # frontend embeds are prepended, so they ride the first chunk
-            batch["frontend_embeds"] = req.frontend_embeds
-        n_valid = chunk + self.cfg.n_frontend_tokens
-        batch = self._pad_tokens(batch, self.cfg, n_valid)
-        last, single_cache = self._prefill(self.params, batch,
-                                           jnp.int32(n_valid))
+        with TraceAnnotation("serving.prefill", rid=req.rid, tokens=chunk):
+            batch = {"tokens": req.tokens[:, :chunk]}
+            if req.frontend_embeds is not None:
+                # frontend embeds are prepended, so they ride the first chunk
+                batch["frontend_embeds"] = req.frontend_embeds
+            n_valid = chunk + self.cfg.n_frontend_tokens
+            batch = self._pad_tokens(batch, self.cfg, n_valid)
+            last, single_cache = self._prefill(self.params, batch,
+                                               jnp.int32(n_valid))
         self.cache = _tree_insert(self.cache, single_cache, slot)
         self.positions = self.positions.at[slot].set(
             chunk + self.cfg.n_frontend_tokens)
@@ -730,6 +754,7 @@ class ContinuousBatchingEngine:
         hashes: List[int] = []
         if hashing:
             if req._block_hashes is None:      # one host sync per admission
+                self.host_syncs += 1
                 req._block_hashes = hash_prompt_blocks(tokens[0].tolist(), bs)
             hashes = req._block_hashes
             # non-mutating probe: size the hit chain without touching
@@ -758,25 +783,28 @@ class ContinuousBatchingEngine:
         if kv.alloc.available() - cached_hits < needed + 1:  # +1: decode block
             return False
         heapq.heappop(self._pending)
+        self._stamp_admitted(req)
         for h in hashes[:n_hit]:
             kv.attach(slot, kv.alloc.lookup(h))
         req.prefix_hit += hit
         self.prefix_hit_tokens += hit
         if chunk:
-            batch = {"tokens": tokens[:, :chunk]}
-            if req.frontend_embeds is not None:
-                batch["frontend_embeds"] = req.frontend_embeds
-            # allocate the prompt's blocks up front (the admission check
-            # above guarantees availability), then scatter K/V straight
-            # into the pools inside the traced prefill — the dense
-            # single-request cache never materializes
-            while (len(kv.slot_blocks[slot])
-                   < kv.blocks_for_tokens(cache_tokens)):
-                kv.grow(slot)
-            batch = self._pad_tokens(batch, self.cfg, cache_tokens)
-            last, kv.pools = self._prefill_paged(
-                self.params, kv.pools, batch, jnp.int32(cache_tokens),
-                kv.tables[slot:slot + 1])
+            with TraceAnnotation("serving.prefill", rid=req.rid,
+                                 tokens=chunk):
+                batch = {"tokens": tokens[:, :chunk]}
+                if req.frontend_embeds is not None:
+                    batch["frontend_embeds"] = req.frontend_embeds
+                # allocate the prompt's blocks up front (the admission
+                # check above guarantees availability), then scatter K/V
+                # straight into the pools inside the traced prefill — the
+                # dense single-request cache never materializes
+                while (len(kv.slot_blocks[slot])
+                       < kv.blocks_for_tokens(cache_tokens)):
+                    kv.grow(slot)
+                batch = self._pad_tokens(batch, self.cfg, cache_tokens)
+                last, kv.pools = self._prefill_paged(
+                    self.params, kv.pools, batch, jnp.int32(cache_tokens),
+                    kv.tables[slot:slot + 1])
             self.cache = kv.pools
             if hashing:
                 for i in range(chunk // bs):
@@ -823,6 +851,7 @@ class ContinuousBatchingEngine:
         if kv.alloc.available() < 1:
             return False
         heapq.heappop(self._pending)
+        self._stamp_admitted(req)
         h = req._handoff
         req._handoff = None
         # ownership was taken at submit; a handoff consumed while queued
@@ -849,6 +878,7 @@ class ContinuousBatchingEngine:
         full block may have been filled by decode-tail ticks), then retains
         each block so they all survive this slot's release."""
         kv = self.kv
+        self.host_syncs += 1
         toks = req.tokens[0].tolist()
         hashes = (req._block_hashes
                   if req._block_hashes is not None
@@ -938,20 +968,22 @@ class ContinuousBatchingEngine:
         kv = self.kv
         bs = kv.block_size
         span = self._spec_m if self.spec is not None else 1
-        for slot in range(self.n_slots):
-            req = self.active[slot]
-            if req is None:
-                continue
-            while (req.cache_pos + span - 1) // bs >= len(kv.slot_blocks[slot]):
-                if kv.grow(slot):
+        with TraceAnnotation("serving.ensure_blocks"):
+            for slot in range(self.n_slots):
+                req = self.active[slot]
+                if req is None:
                     continue
-                victim = self._pick_victim()
-                if victim is None:      # unreachable: submit() guards size
-                    raise MemoryError("paged KV pool exhausted with no "
-                                      "preemptible request")
-                self._preempt(victim)
-                if victim == slot:
-                    break               # this slot itself was evicted
+                while ((req.cache_pos + span - 1) // bs
+                       >= len(kv.slot_blocks[slot])):
+                    if kv.grow(slot):
+                        continue
+                    victim = self._pick_victim()
+                    if victim is None:  # unreachable: submit() guards size
+                        raise MemoryError("paged KV pool exhausted with no "
+                                          "preemptible request")
+                    self._preempt(victim)
+                    if victim == slot:
+                        break           # this slot itself was evicted
 
     def _prompt_token(self, req: GenRequest, i: int):
         return req.feed_tokens[0, i]
@@ -960,8 +992,17 @@ class ContinuousBatchingEngine:
         self.last_tokens = self.last_tokens.at[slot].set(
             jnp.asarray(token, jnp.int32).reshape(self.last_tokens.shape[1:]))
 
+    def _stamp_admitted(self, req: GenRequest) -> None:
+        if not req.admitted_at:        # a resume keeps the first admission
+            # repro: allow-wallclock -- queueing interval vs submitted_at
+            req.admitted_at = time.perf_counter()
+
     def _record(self, req: GenRequest, token) -> None:
-        tok = token.tolist() if hasattr(token, "tolist") else token
+        if isinstance(token, jax.Array):
+            self.host_syncs += 1
+            tok = token.tolist()
+        else:
+            tok = token
         if not req.out_tokens:
             # repro: allow-wallclock -- TTFT interval vs submitted_at
             req.first_token_at = time.perf_counter()
@@ -1014,9 +1055,11 @@ class ContinuousBatchingEngine:
                 req = self.active[s]
                 if req.sampling.is_greedy:
                     if batch_argmax is None:
+                        self.host_syncs += 1
                         batch_argmax = jnp.argmax(last, axis=-1).tolist()
                     proposals[s].append(int(batch_argmax[s]))
                 else:
+                    self.host_syncs += 1       # the drawn token
                     tok, probs = draft_propose(last[s], req.sampling,
                                                n0[s] + j)
                     proposals[s].append(tok)
@@ -1052,19 +1095,31 @@ class ContinuousBatchingEngine:
             else:
                 t_f = min(m, req.feed_len - req.n_consumed)
                 t_feed[s] = t_f
+                self.host_syncs += 1
                 row = [int(t) for t in
                        req.feed_tokens[0, req.n_consumed:
                                        req.n_consumed + t_f].tolist()]
             cand[s][:len(row)] = row
         cand_arr = jnp.asarray(cand, jnp.int32)
-        if self.paged:
-            logits, self.kv.pools = self._verify_paged(
-                self.params, self.kv.pools, cand_arr, self.positions,
-                self.kv.tables)
-            self.cache = self.kv.pools
-        else:
-            logits, self.cache = self._verify(self.params, self.cache,
-                                              cand_arr, self.positions)
+        with TraceAnnotation("serving.decode", live=len(active_idx)):
+            if self.paged:
+                logits, self.kv.pools = self._verify_paged(
+                    self.params, self.kv.pools, cand_arr, self.positions,
+                    self.kv.tables)
+                self.cache = self.kv.pools
+            else:
+                logits, self.cache = self._verify(self.params, self.cache,
+                                                  cand_arr, self.positions)
+        with TraceAnnotation("serving.harvest"):
+            return self._harvest_spec(active_idx, proposals, dprobs, t_feed,
+                                      logits)
+
+    def _harvest_spec(self, active_idx: List[int],
+                      proposals: Dict[int, List[int]],
+                      dprobs: Dict[int, List[Any]], t_feed: Dict[int, int],
+                      logits) -> int:
+        """Accept and commit each slot's share of one verify pass, then
+        roll back positions and blocks past what was committed."""
         self.steps += 1
         tgt_argmax = None
         pos_delta = [0] * self.n_slots
@@ -1078,6 +1133,7 @@ class ContinuousBatchingEngine:
                 k_s = len(proposals[s])
                 if req.sampling.is_greedy:
                     if tgt_argmax is None:
+                        self.host_syncs += 1
                         tgt_argmax = jnp.argmax(logits, axis=-1).tolist()
                     n_acc, toks = greedy_accept(proposals[s],
                                                 tgt_argmax[s][:k_s + 1])
@@ -1085,6 +1141,9 @@ class ContinuousBatchingEngine:
                     n_acc, toks = rejection_sample(
                         proposals[s], dprobs[s], logits[s], req.sampling,
                         len(req.out_tokens))
+                    # two reads (draw, ratio) per draft token judged, one
+                    # for the correction or bonus token
+                    self.host_syncs += 2 * min(n_acc + 1, k_s) + 1
                 occupied, c = self._commit_spec(s, req, n_acc, k_s, toks)
                 n_occupied += occupied
                 pos_delta[s] = c
@@ -1124,7 +1183,7 @@ class ContinuousBatchingEngine:
             return 1
         nxt = sample(logits[slot, t_f - 1], req.sampling,
                      len(req.out_tokens))
-        self._record(req, int(nxt))
+        self._record(req, nxt)
         self._set_last(slot, nxt)
         return 0 if req.done else 1
 
@@ -1164,21 +1223,36 @@ class ContinuousBatchingEngine:
     # ---------------------------------------------------------------- #
     def step(self) -> int:
         """Admit -> one batched decode step -> harvest. Returns #occupied."""
-        if self.spec is not None:
-            return self._step_spec()
+        with TraceAnnotation("serving.step", step=self.steps):
+            if self.spec is not None:
+                return self._step_spec()
+            return self._step()
+
+    def _step(self) -> int:
         self._admit()
         if self.paged:
             self._ensure_blocks()                # may preempt under pressure
-        if not any(r is not None for r in self.active):
+        live = [r for r in self.active if r is not None]
+        if not live:
             return 0
-        if self.paged:
-            logits, self.kv.pools = self._decode_paged(
-                self.params, self.kv.pools, self.last_tokens,
-                self.positions, self.kv.tables)
-            self.cache = self.kv.pools
-        else:
-            logits, self.cache = self._decode(self.params, self.cache,
-                                              self.last_tokens, self.positions)
+        # args: occupied slots, and the cache positions they attend
+        with TraceAnnotation("serving.decode", live=len(live),
+                             context=sum(r.cache_pos + 1 for r in live)):
+            if self.paged:
+                logits, self.kv.pools = self._decode_paged(
+                    self.params, self.kv.pools, self.last_tokens,
+                    self.positions, self.kv.tables)
+                self.cache = self.kv.pools
+            else:
+                logits, self.cache = self._decode(
+                    self.params, self.cache, self.last_tokens,
+                    self.positions)
+        with TraceAnnotation("serving.harvest"):
+            return self._harvest(logits)
+
+    def _harvest(self, logits) -> int:
+        """Emit each decode slot's next token, or feed a prompt slot its
+        next prompt token; free the slots that finished."""
         self.positions = self.positions + 1
         last = logits[:, -1]                     # [B, V] or [B, K, V]
         # one batched argmax serves every greedy slot (the common case);
@@ -1248,6 +1322,7 @@ class ContinuousBatchingEngine:
             generated_tokens=sum(len(r.out_tokens or []) for r in reqs),
             prefill_tokens=self.prefill_tokens,
             preempted=self.preempted_total,
+            host_syncs=self.host_syncs,
             cancelled=sum(1 for r in reqs if r.status == "cancelled"),
             prefix_hit_tokens=self.prefix_hit_tokens,
             prompt_tokens_computed=self.prompt_tokens_computed,
