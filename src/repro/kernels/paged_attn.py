@@ -4,9 +4,13 @@ Decode attention that reads K/V straight out of the shared block pool via
 per-sequence block tables — the dense ``[B, S]`` cache view never
 materializes in HBM. The block table (and per-sequence positions) ride the
 TPU scalar-prefetch path: the grid is ``(B, M)`` and the *index map* of the
-K/V pool specs picks physical block ``tables[b, m]`` for grid step ``m``,
-so the pipeline DMAs exactly the blocks each sequence owns — paging is
-free, it happens in the prefetch unit.
+K/V pool specs picks physical block ``tables[b, min(m, last[b])]`` for grid
+step ``m`` — paging happens in the prefetch unit. Each sequence's walk stops
+at its last live block ``last = pos // bs``: past it the block index
+repeats, so the pipeline issues no DMA, and the body computes nothing.
+Only the grid steps themselves remain, ``M`` per sequence. ``last`` is
+prefetched beside ``pos`` rather than divided out per grid step: the
+scalar division cost about 0.05 us of every step, skipped or live (v5e).
 
 Each grid step takes one block with all ``Hkv`` heads, ``(1, bs, Hkv,
 hd)``: on the pool's ``[N, bs, Hkv, hd]`` layout a one-head block would
@@ -81,7 +85,7 @@ def _q4_head(h, q_refs, kv_refs):
     return scores, [v_even, v_odd]
 
 
-def _kernel(head_fn, n_q, hd, tables_ref, pos_ref, *refs):
+def _kernel(head_fn, n_q, hd, tables_ref, pos_ref, last_ref, *refs):
     """refs: n_q query parts, the K/V (+ scale) blocks, n_q outputs, n_q
     accumulators, running max, normalizer."""
     n_kv = len(refs) - 3 * n_q - 2
@@ -96,13 +100,18 @@ def _kernel(head_fn, n_q, hd, tables_ref, pos_ref, *refs):
     def _init():
         osm.init_state(acc_refs, m_ref, l_ref)
 
-    slots = mi * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    ok = (slots <= pos_ref[bi]) & (tables_ref[bi, mi] >= 0)
-    for h in range(hkv):
-        scores, vals = head_fn(h, q_refs, kv_refs)
-        scores = jnp.where(ok, scores / jnp.sqrt(jnp.float32(hd)),
-                           osm.NEG_INF)
-        osm.step(h, scores, vals, acc_refs, m_ref, l_ref)
+    # blocks past the last live one are skipped, not masked: a masked block
+    # adds nothing to the online softmax, but 0 * NaN from a stale block
+    # would reach the value contraction
+    @pl.when(mi <= last_ref[bi])
+    def _live():
+        slots = mi * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        ok = (slots <= pos_ref[bi]) & (tables_ref[bi, mi] >= 0)
+        for h in range(hkv):
+            scores, vals = head_fn(h, q_refs, kv_refs)
+            scores = jnp.where(ok, scores / jnp.sqrt(jnp.float32(hd)),
+                               osm.NEG_INF)
+            osm.step(h, scores, vals, acc_refs, m_ref, l_ref)
 
     @pl.when(mi == pl.num_programs(1) - 1)
     def _finish():
@@ -113,20 +122,24 @@ def _kernel(head_fn, n_q, hd, tables_ref, pos_ref, *refs):
 def _pool_spec(block_shape):
     # index map args: (grid indices..., scalar-prefetch refs) — block m of
     # sequence b lives at physical pool row tables[b, m] (clamped: -1 reads
-    # the reserved trash block, masked out in the kernel)
+    # the reserved trash block, masked out in the kernel). Past the last
+    # live block the index repeats, so the pipeline fetches nothing new.
     zeros = (0,) * (len(block_shape) - 1)
-    return pl.BlockSpec(
-        block_shape,
-        lambda b, m, tabs, pos: (jnp.maximum(tabs[b, m], 0), *zeros))
+
+    def index(b, m, tabs, pos, last):
+        return (jnp.maximum(tabs[b, jnp.minimum(m, last[b])], 0), *zeros)
+
+    return pl.BlockSpec(block_shape, index)
 
 
 def _call(head_fn, qs, pools, tables, pos, hd, interpret):
     b, hkv, g, w = qs[0].shape
     m = tables.shape[1]
+    pos = pos.astype(jnp.int32)
     row_spec = pl.BlockSpec((1, hkv, g, w),
-                            lambda b_, m_, tabs, pos_: (b_, 0, 0, 0))
+                            lambda b_, m_, *prefetch: (b_, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, m),
         in_specs=[row_spec] * len(qs)
         + [_pool_spec((1,) + p.shape[1:]) for p in pools],
@@ -140,7 +153,8 @@ def _call(head_fn, qs, pools, tables, pos, hd, interpret):
         out_shape=[jax.ShapeDtypeStruct((b, hkv, g, w), jnp.float32)]
         * len(qs),
         interpret=interpret,
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), *qs, *pools)
+    )(tables.astype(jnp.int32), pos, pos // pools[0].shape[1], *qs,
+      *pools)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -1235,9 +1235,12 @@ class ContinuousBatchingEngine:
         live = [r for r in self.active if r is not None]
         if not live:
             return 0
-        # args: occupied slots, and the cache positions they attend
-        with TraceAnnotation("serving.decode", live=len(live),
-                             context=sum(r.cache_pos + 1 for r in live)):
+        # args: occupied slots, the cache positions they attend, and (paged)
+        # the blocks the decode kernel computes over all slots
+        args = {"live": len(live), "context": sum(r.cache_pos + 1 for r in live)}
+        if self.paged:
+            args["blocks"] = self._walked_blocks()
+        with TraceAnnotation("serving.decode", **args):
             if self.paged:
                 logits, self.kv.pools = self._decode_paged(
                     self.params, self.kv.pools, self.last_tokens,
@@ -1250,10 +1253,21 @@ class ContinuousBatchingEngine:
         with TraceAnnotation("serving.harvest"):
             return self._harvest(logits)
 
+    def _walked_blocks(self) -> int:
+        """Blocks the paged decode kernel computes this step, from the host
+        mirror: each slot walks up to its last live block, an idle slot
+        (position 0) its block 0 alone."""
+        bs, m = self.kv.block_size, self.kv.max_blocks
+        return sum(1 if r is None else min(r.cache_pos // bs, m - 1) + 1
+                   for r in self.active)
+
     def _harvest(self, logits) -> int:
         """Emit each decode slot's next token, or feed a prompt slot its
         next prompt token; free the slots that finished."""
-        self.positions = self.positions + 1
+        # idle slots stay at position 0: the paged kernel's walk is bounded
+        # by position, so an idle slot costs one block
+        self.positions = self.positions + jnp.asarray(
+            [r is not None for r in self.active], jnp.int32)
         last = logits[:, -1]                     # [B, V] or [B, K, V]
         # one batched argmax serves every greedy slot (the common case);
         # only non-greedy requests pay a per-slot sampling dispatch

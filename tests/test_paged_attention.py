@@ -102,6 +102,83 @@ def test_paged_kernel_matches_ref_int4():
                                rtol=1e-5, atol=1e-5)
 
 
+KV_KINDS = ("fp32", "bf16", "int8", "int4")
+_KERNELS = {
+    "fp32": (paged_attn.paged_decode_attention, ref.paged_decode_ref),
+    "bf16": (paged_attn.paged_decode_attention, ref.paged_decode_ref),
+    "int8": (paged_attn.paged_qdecode_attention, ref.paged_qdecode_ref),
+    "int4": (paged_attn.paged_q4decode_attention, ref.paged_q4decode_ref),
+}
+
+
+def _ragged_case(seed=7, hkv=2, g=2, hd=32, bs=4, m=6):
+    """Five slots whose walks end in different places: ``pos`` at
+    ``2*bs - 1`` (last slot of a block) and ``2*bs`` (first of the next),
+    one slot at full ``M``, an idle slot (pos 0, table all -1) and a short
+    slot. Every live slot's table reaches blocks past its last live one;
+    slot 1's last entry is unallocated."""
+    b = 5
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, hkv, g, hd))
+    n = 1 + b * m                                 # block 0: the trash block
+    k_pool = jax.random.normal(ks[1], (n, bs, hkv, hd))
+    v_pool = jax.random.normal(ks[2], (n, bs, hkv, hd))
+    tables = np.arange(1, n, dtype=np.int32).reshape(b, m)
+    tables[1, -1] = -1
+    tables[3] = -1
+    pos = jnp.array([2 * bs - 1, 2 * bs, m * bs - 1, 0, bs + 1], jnp.int32)
+    return q, k_pool, v_pool, jnp.asarray(tables), pos
+
+
+def _pools(kind, k_pool, v_pool, poison=()):
+    """The kernel's pool arguments for KV tier ``kind``; the pool rows in
+    ``poison`` hold NaN (fp: the payload; int8/int4: the scales)."""
+    rows = jnp.asarray(poison, jnp.int32)
+    if kind in ("fp32", "bf16"):
+        dt = jnp.bfloat16 if kind == "bf16" else jnp.float32
+        return tuple(p.astype(dt).at[rows].set(jnp.nan)
+                     for p in (k_pool, v_pool))
+    quant = _quant if kind == "int8" else quantize_kv_int4
+    (kq, kscale), (vq, vscale) = quant(k_pool), quant(v_pool)
+    return (kq, kscale.at[rows].set(jnp.nan), vq,
+            vscale.at[rows].set(jnp.nan))
+
+
+@pytest.mark.parametrize("kind", KV_KINDS)
+def test_paged_kernel_ragged_walk_matches_ref(kind):
+    """Each slot's walk stops at its own last live block: live slots match
+    the oracle wherever ``pos`` falls in a block, and the idle slot is
+    exactly 0."""
+    q, k_pool, v_pool, tables, pos = _ragged_case()
+    kernel, oracle = _KERNELS[kind]
+    pools = _pools(kind, k_pool, v_pool)
+    got = np.asarray(kernel(q, *pools, tables, pos, interpret=True))
+    assert np.all(got[3] == 0.0)
+    live = np.array([0, 1, 2, 4])
+    want = oracle(q[live], *pools, tables[live], pos[live])
+    np.testing.assert_allclose(got[live], np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", KV_KINDS)
+def test_paged_kernel_skips_poisoned_tail(kind):
+    """Blocks a slot's table reaches past its last live block may hold
+    anything (a stale or recycled block): with NaN written there the output
+    is finite and bit-equal to the clean pool's."""
+    q, k_pool, v_pool, tables, pos = _ragged_case(seed=8)
+    kernel, _ = _KERNELS[kind]
+    tabs, last = np.asarray(tables), np.asarray(pos) // k_pool.shape[1]
+    poison = [int(t) for row, end in zip(tabs, last) for t in row[end + 1:]
+              if t >= 0]
+    assert poison
+    clean = kernel(q, *_pools(kind, k_pool, v_pool), tables, pos,
+                   interpret=True)
+    got = np.asarray(kernel(q, *_pools(kind, k_pool, v_pool, poison),
+                            tables, pos, interpret=True))
+    assert np.all(np.isfinite(got))
+    np.testing.assert_array_equal(got, np.asarray(clean))
+
+
 def test_int4_paged_close_to_fp32_paged():
     """int4-KV accuracy bound: grouped 4-bit quantization perturbs paged
     attention outputs by less than ~20% of the value scale on unit-normal

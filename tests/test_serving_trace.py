@@ -136,3 +136,40 @@ def test_decode_program_is_named(setup):
             engine.params, kv.pools, batch, jnp.int32(8),
             kv.tables[:1]).as_text()
     assert "module @jit_prefill_paged " in text
+
+
+def test_decode_span_counts_walked_blocks(setup, tmp_path):
+    """``serving.decode``'s ``blocks`` is what the paged kernel walks: per
+    slot ``min(pos // bs, M - 1) + 1`` over the positions the decode step
+    is given, where an idle slot sits at position 0 and costs one block."""
+    cfg, params = setup
+    engine = _engine(params, cfg, n_slots=4)
+    engine.warmup(prompt_len=20)
+    bs, m = engine.kv.block_size, engine.kv.max_blocks
+    seen = []
+    decode = engine._decode_paged
+
+    def recording(p, c, t, pos, tabs):
+        seen.append(jax.device_get(pos))
+        return decode(p, c, t, pos, tabs)
+
+    engine._decode_paged = recording
+    # the longest request reaches the table's last block (M - 1);
+    # slot 3 stays idle throughout and slot 1 idles once its request ends
+    reqs = [engine.submit(p, max_new_tokens=n)
+            for p, n in zip(_prompts(cfg, (50, 12, 20), seed=5), (12, 3, 6))]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.run()
+    finally:
+        jax.profiler.stop_trace()
+    assert all(r.done for r in reqs)
+    decodes = sorted((e for e in _host_events(str(tmp_path))
+                      if e[0] == "serving.decode"), key=lambda e: e[1])
+    assert len(decodes) == len(seen) > 0
+    assert max(int(p.max()) for p in seen) > (m - 1) * bs
+    assert any(int(p[1]) == 0 for p in seen[1:])
+    for (_, _, _, args), pos in zip(decodes, seen):
+        assert pos[3] == 0
+        want = sum(min(int(x) // bs, m - 1) + 1 for x in pos)
+        assert int(args["blocks"]) == want
