@@ -7,7 +7,9 @@ then the reference over the checked requests: the program's widest and mean
 gaps, and, on the first ``--controls-on`` seeds, the gaps of the first
 choices of the reference computed in each lower precision (``--controls``).
 With ``--variant``, the program serves that weight variant of its own
-(``dynamic_int8``): the control where the program has the lower precision.
+(``dynamic_int8``): the control where the program has the lower precision
+(not at tp > 1, where the program refuses quantized weights; there the
+reference's own lower precisions are the control).
 
     python3 benchmarks/chip/control.py --workload stablelm-1.6b.chat \\
         --seeds 101,102,103 --seconds 15 --controls fp8,int8
@@ -16,8 +18,10 @@ With ``--variant``, the program serves that weight variant of its own
 
 The lower reading of a number is its largest over the program's seeds, the
 upper its smallest over a control's; ``PERF.md`` gives both and the limit
-set between them in ``cells/<cell>.json``. The benchmark's runs do not run
-the controls.
+set between them in ``cells/<cell>.json``. Each control is judged by those
+limits as the program is (a ``--variant`` run by its own ``correct``), and
+the command exits 1 where any control comes out correct. The benchmark's
+runs do not run the controls.
 """
 from __future__ import annotations
 
@@ -61,8 +65,12 @@ def main(argv=None) -> int:
             log(f"control.py: {e}")
             return 3
         log("control run " + json.dumps(out))
+        judged = dict(out.get("control_correct", {}))
+        if args.variant:
+            judged[args.variant] = out["correct"]
         row = {"seed": seed, "variant": args.variant,
                "program": out["gaps"], "control": out.get("control_gap", {}),
+               "control_correct": judged,
                "short": out["check"]["short_requests"]["value"],
                "attempted": out["attempted"], "failed": out["failed"]}
         rows.append(row)
@@ -72,9 +80,14 @@ def main(argv=None) -> int:
     least = {q: {k: min(r["control"][q][k] for r in rows
                         if q in r["control"]) for k in nums}
              for q in controls if any(q in r["control"] for r in rows)}
+    passed = sorted({f"{q}@{r['seed']}" for r in rows
+                     for q, ok in r["control_correct"].items() if ok})
     print(json.dumps({"workload": args.workload, "variant": args.variant,
                       "program_max": most, "control_min": least,
-                      "rows": rows}))
+                      "control_passed": passed, "rows": rows}))
+    if passed:
+        log(f"control.py: controls that came out correct: {passed}")
+        return 1
     return 0
 
 

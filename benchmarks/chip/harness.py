@@ -8,7 +8,9 @@ the limit of each number the correctness check compares, are in
 
 1. makes the weights on the device from the seed and builds the engine the
    way a user does: ``ContinuousBatchingEngine.from_artifact(..., paged=True)``
-   on the default backend (``pallas-tpu`` on one chip);
+   on the default backend (``pallas-tpu`` on one chip), at the tensor-parallel
+   width the configuration states (``tp``, ``tp_combine``; the weights are
+   then made where the program places them, split over the chips);
 2. warms every program the window uses (one prefill per power-of-two
    bucket the prompts and preemption resumes reach, and the decode step);
 3. offers the mix's arrivals open-loop, due on the wall clock from
@@ -137,9 +139,48 @@ class CompileCounter:
 
 def _engine_kwargs(cell: dict, backend) -> dict:
     eng, conf = cell["mix"]["engine"], cell["conf"]
-    return dict(backend=backend, paged=True, n_slots=eng["n_slots"],
-                max_len=eng["max_len"], block_size=eng["block_size"],
-                kv_budget_bytes=conf["kv_budget_bytes"])
+    kw = dict(backend=backend, paged=True, n_slots=eng["n_slots"],
+              max_len=eng["max_len"], block_size=eng["block_size"],
+              kv_budget_bytes=conf["kv_budget_bytes"])
+    if conf["tp"] > 1:
+        # the engine swaps its backend for the ``-tp`` twin
+        kw.update(tp=conf["tp"], tp_combine=conf["tp_combine"])
+    return kw
+
+
+def tp_shardings(cfg, conf: dict):
+    """Where the program places each weight when it serves ``conf`` at
+    ``conf["tp"]`` > 1 (its own ``tp_param_specs`` on its own serving
+    mesh), so that the weights are made there and no leaf is ever whole on
+    one chip; None at tp 1."""
+    if conf["tp"] == 1:
+        return None
+    import jax
+    from jax.sharding import NamedSharding
+
+    from repro.launch.mesh import make_tp_mesh
+    from repro.models.sharding import tp_param_specs
+
+    mesh = make_tp_mesh(conf["tp"])
+    specs = tp_param_specs(W.abstract(cfg), mesh, conf["tp_combine"])
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+
+
+def reference_shardings(cfg, chips: int):
+    """Where the reference's weights are made at ``chips`` > 1: every
+    stacked leaf split on its layer axis over the cell's chips, the rest
+    on each of them (the reference takes one layer at a time to the first
+    chip); None on one chip."""
+    if chips == 1:
+        return None
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:chips]), ("layers",))
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: NamedSharding(mesh, P("layers") if any(
+            getattr(k, "key", None) == "layers" for k in path) else P()),
+        W.abstract(cfg))
 
 
 def warm_lengths(mix: dict) -> List[int]:
@@ -172,7 +213,7 @@ def build(cell: dict, seed: int, backend=None, log=print,
 
     cfg = model_config(cell["conf"])
     t = clock()
-    params = W.make(cfg, seed)
+    params = W.make(cfg, seed, tp_shardings(cfg, cell["conf"]))
     jax.block_until_ready(params)
     log(f"weights: {cfg.name} from seed {seed} in {clock() - t:.3f} s")
     art = ModelArtifact.create(cfg.name, f"seed{seed}", params, cfg)
@@ -392,6 +433,8 @@ def end_to_end(reqs, run: dict, seconds: float) -> dict:
             "ttft_p50_s": percentile(ttft, 50),
             "itl_p95_ms": percentile(gaps, 95) * 1e3,
             "itl_p50_ms": percentile(gaps, 50) * 1e3,
+            "itl_p99_ms": percentile(gaps, 99) * 1e3,
+            "itl_mean_ms": float(np.mean(gaps)) * 1e3 if gaps else 0.0,
             "output_tok_s": n_tok / seconds, "window_tokens": n_tok,
             "decode_step_ms": 1e3 * seconds / steps if steps else 0.0,
             "window_steps": steps,
@@ -423,7 +466,7 @@ def check(cell: dict, cfg, reqs, served: Dict[int, list], seed: int,
     the reference's best, and whether each request was served whole."""
     conf, mix = cell["conf"], cell["mix"]
     t = clock()
-    params = W.make(cfg, seed)
+    params = W.make(cfg, seed, reference_shardings(cfg, cell["chips"]))
     seqs = [(reqs[i].prompt, served[i]) for i in sorted(served)]
     out = R.gaps(params, conf["model"], seqs,
                  pad_to=mix["engine"]["max_len"],
@@ -446,6 +489,12 @@ def check(cell: dict, cfg, reqs, served: Dict[int, list], seed: int,
     return {"widest_gap": widest, "mean_gap": mean, "control_gap": ctl,
             "short": short,
             "checked_tokens": n_tok, "checked_requests": len(seqs)}
+
+
+def judged(nums: dict, limits: dict) -> bool:
+    """Whether every number a cell compares lies within its limit: the
+    rule for the program's gaps and for each control's alike."""
+    return all(nums[k] <= v for k, v in limits.items())
 
 
 def read_metrics(cell: dict, rec: dict) -> Dict[str, dict]:
@@ -505,6 +554,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     setup_s = res["t0"] - t_start            # process start to window open
     used = devices[:chips]
     peak = _peak_bytes(used)
+    stats = [d.memory_stats() or {} for d in used]
+    log("peak bytes in use per chip: "
+        f"{[int(m.get('peak_bytes_in_use', 0)) for m in stats]} of "
+        f"{[int(m.get('bytes_limit', 0)) for m in stats]}")
     snap = res["snap"]
     admitted_w = sum(1 for k, t in enumerate(res["admitted"])
                      if res["t0"] <= t < res["t_close"])
@@ -524,7 +577,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     log(f"compiles inside the window: {counter.counts} (expected 0)")
     log(f"end to end: ttft p50 {e2e['ttft_p50_s']:.4f} s p90 "
         f"{e2e['ttft_p90_s']:.4f} s; itl p50 {e2e['itl_p50_ms']:.3f} ms "
-        f"p95 {e2e['itl_p95_ms']:.3f} ms over {e2e['n_gaps']} gaps; "
+        f"p95 {e2e['itl_p95_ms']:.3f} ms p99 {e2e['itl_p99_ms']:.3f} ms "
+        f"mean {e2e['itl_mean_ms']:.3f} ms over {e2e['n_gaps']} gaps; "
         f"{e2e['output_tok_s']:.3f} tok/s; peak {peak} B")
     queue_wait = [res["admitted"][k] - (res["t0"] + r.due)
                   for k, r in enumerate(reqs)
@@ -544,8 +598,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     chk = check(cell, cfg, reqs, served, seed, controls, log)
     # the numbers compared are those the cell's file gives a limit
     limits = own["limits"]
-    correct = (bool(served) and chk["short"] == 0
-               and all(chk[k] <= v for k, v in limits.items()))
+    correct = bool(served) and chk["short"] == 0 and judged(chk, limits)
     dev = devices[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": peak}
@@ -553,7 +606,6 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
            "failed": e2e["failed"]}
     if not trace:
         vals = {"ttft_p90_s": e2e["ttft_p90_s"],
-                "itl_p95_ms": e2e["itl_p95_ms"],
                 "itl_p50_ms": e2e["itl_p50_ms"],
                 "hbm_peak_gb": peak / 1e9, "setup_s": setup_s}
         units = {m["name"]: m["unit"] for m in cell["end_to_end"]}
@@ -567,7 +619,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
                "prefill_calls": spans.prefill_calls,
                "queue_wait_s": queue_wait, "admitted": admitted_w,
                "preempted": preempted_w, "kv_peak_blocks": snap["kv_peak"],
-               "kv_blocks": n_blocks, "live_slots": live}
+               "kv_blocks": n_blocks, "live_slots": live,
+               "itl_gaps": e2e["n_gaps"], "itl_p95_ms": e2e["itl_p95_ms"]}
         out["metrics"] = read_metrics(cell, rec)
         if summary is not None:
             busy, window = T.busy_share(summary)
@@ -579,6 +632,13 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
     out["device"] = device
     if controls:
         out["control_gap"] = chk["control_gap"]
+        # each control put in the program's place, judged as the program
+        # is: it has to come out as not correct
+        out["control_correct"] = {q: judged(g, limits)
+                                  for q, g in chk["control_gap"].items()}
+        for q, ok in out["control_correct"].items():
+            log(f"control: {q} {chk['control_gap'][q]!r} "
+                f"{'correct' if ok else 'not correct'}")
     # both gaps, whether or not the cell compares them (the readings limits
     # are set from)
     out["gaps"] = {k: chk[k] for k in ("widest_gap", "mean_gap")}

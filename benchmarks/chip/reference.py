@@ -8,7 +8,9 @@ pair), grouped query heads (query head i reads kv head i // (Hq / Hkv)),
 causal softmax at 1 / sqrt(head_dim); a SwiGLU MLP whose input projection
 holds the gate and then the up columns; a final RMSNorm and an untied head.
 No cache, no kernels, no batching: each sequence runs whole, one layer at a
-time, with float32 matmuls at ``highest`` precision.
+time, with float32 matmuls at ``highest`` precision. Weights split over
+several chips on their layer axis are read one layer at a time on the
+first chip, where that layer is upcast.
 
 ``quant`` puts a lower precision in the matmuls of the linear layers and
 the head (weights per output column, activations per token): ``"fp8"``
@@ -117,13 +119,40 @@ def dims_of(model: dict) -> tuple:
             float(model["rms_norm_eps"]), float(model["rope_theta"]))
 
 
+def _spread(params) -> bool:
+    """Whether the weights lie on more than one device."""
+    return len(params["layers"]["ln1"].sharding.device_set) > 1
+
+
+def _layer_to(layers, i, device):
+    """Layer ``i`` of stacked leaves split on their layer axis, as a stack
+    of one on ``device``: sliced from the shard that holds it, and only
+    that layer moved."""
+    def one(a):
+        for sh in a.addressable_shards:
+            lo, hi, _ = sh.index[0].indices(a.shape[0])
+            if lo <= i < hi:
+                return jax.device_put(sh.data[i - lo:i - lo + 1], device)
+        raise ValueError(f"no shard holds layer {i}")
+    return jax.tree.map(one, layers)
+
+
 def hidden(params, tokens, dims, quant=None):
-    """Final hidden states [S, d] of one sequence ``tokens`` [S]."""
+    """Final hidden states [S, d] of one sequence ``tokens`` [S]. Weights
+    spread over several devices (stacked leaves split on the layer axis)
+    are read one layer at a time on the first of them."""
     x = jnp.take(params["embed"], jnp.asarray(tokens), axis=0).astype(
         jnp.float32)
     n_layers = params["layers"]["ln1"].shape[0]
+    if not _spread(params):
+        for i in range(n_layers):
+            x = _layer(x, params["layers"], jnp.int32(i), dims, quant)
+        return x
+    dev = jax.devices()[0]
+    x = jax.device_put(x, dev)
     for i in range(n_layers):
-        x = _layer(x, params["layers"], jnp.int32(i), dims, quant)
+        x = _layer(x, _layer_to(params["layers"], i, dev), jnp.int32(0),
+                   dims, quant)
     return x
 
 
@@ -140,6 +169,9 @@ def gaps(params, model: dict, seqs, pad_to: int, read_len: int,
     """
     dims = dims_of(model)
     eps = dims[4]
+    dev = jax.devices()[0]
+    final_norm = jax.device_put(params["final_norm"], dev)
+    unembed = jax.device_put(params["unembed"], dev)
     out = []
     with jax.default_matmul_precision("highest"):
         for prompt, served in seqs:
@@ -157,15 +189,14 @@ def gaps(params, model: dict, seqs, pad_to: int, read_len: int,
             valid = np.arange(read_len) < len(served)
             rows, tgt = jnp.asarray(rows), jnp.asarray(tgt)
             valid = jnp.asarray(valid)
-            ref = _head(hidden(params, toks, dims), params["final_norm"],
-                        params["unembed"], rows, eps, None)
+            ref = _head(hidden(params, toks, dims), final_norm, unembed,
+                        rows, eps, None)
             g_served, _ = _gaps(ref, ref, tgt, valid)
             n = len(served)
             ctl = {}
             for quant in controls:
                 other = _head(hidden(params, toks, dims, quant),
-                              params["final_norm"], params["unembed"], rows,
-                              eps, quant)
+                              final_norm, unembed, rows, eps, quant)
                 ctl[quant] = np.asarray(_gaps(ref, other, tgt, valid)[1])[:n]
                 del other
             out.append((np.asarray(g_served)[:n], ctl))

@@ -8,6 +8,8 @@ import math
 import os
 import sys
 
+import pytest
+
 CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, CHIP)
 sys.path.insert(0, os.path.join(CHIP, "tests"))
@@ -28,6 +30,13 @@ def test_fp8_control_fails_where_the_program_passes():
     assert upper >= 3 * max(lower, 1e-3)
     limit = math.sqrt(max(lower, 1e-3) * upper)   # between, as on the chip
     assert lower <= limit < upper
+    # the run judges the control by the cell's limits as it judges the
+    # program; under the limit set between the readings it is not correct
+    assert out["control_correct"] == {"fp8": harness.judged(
+        out["control_gap"]["fp8"], cell["own"]["limits"])}
+    assert harness.judged(out["gaps"], {"widest_gap": limit})
+    assert not harness.judged(out["control_gap"]["fp8"],
+                              {"widest_gap": limit})
 
 
 def test_program_int8_variant_serves_as_the_control():
@@ -47,3 +56,33 @@ def test_program_int8_variant_serves_as_the_control():
     assert set(ctl["gaps"]) == {"widest_gap", "mean_gap"}
     assert ctl["gaps"] != sound["gaps"]
     assert ctl["gaps"]["widest_gap"] >= ctl["gaps"]["mean_gap"] > 0
+
+
+@pytest.mark.parametrize("variant,control_ok,want", [
+    ("", False, 0), ("", True, 1),
+    ("dynamic_int8", False, 0), ("dynamic_int8", True, 1)])
+def test_control_command_fails_where_a_control_comes_out_correct(
+        monkeypatch, variant, control_ok, want):
+    """``control.py`` exits 1 where a control passes the cell's limits: a
+    lower precision of the reference by its ``control_correct``, the
+    program's own variant by the run's ``correct``."""
+    # the command names its compilation cache as it is imported; the
+    # environment is given back after the test
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    import control
+
+    def fake_run(cell, seed, *_a, controls=(), variant=None, **_k):
+        gaps = {"widest_gap": 0.01, "mean_gap": 0.001}
+        out = {"correct": control_ok if variant else True, "attempted": 3,
+               "failed": 0, "gaps": gaps,
+               "check": {"short_requests": {"value": 0, "limit": 0}}}
+        if controls:
+            out["control_gap"] = {q: gaps for q in controls}
+            out["control_correct"] = {q: control_ok for q in controls}
+        return out
+
+    monkeypatch.setattr(control.H, "run", fake_run)
+    monkeypatch.setattr(control.H, "load_cell", lambda _n: {})
+    args = ["--workload", "x", "--seeds", "1,2", "--controls",
+            "" if variant else "int8", "--variant", variant]
+    assert control.main(args) == want

@@ -41,6 +41,21 @@ def test_op_name():
     assert T.op_name("%all-reduce.3 = f32[8]") == "all-reduce"
 
 
+def test_collective_named_by_its_operation():
+    # JAX names an all-reduce after its primitive; the trace's text of the
+    # instruction carries the operation
+    assert T.op_name("%psum.36 = bf16[32,1,5120]{2,0,1} all-reduce("
+                     "bf16[32,1,5120] %x), channel_id=1") == "all-reduce"
+    assert T.op_name("%ar.2 = (bf16[8], bf16[8]) all-reduce-start("
+                     "bf16[8] %a)") == "all-reduce-start"
+    assert T.op_name("%all-gather.1 = bf16[8,4] all-gather(bf16[8,2] %a)") \
+        == "all-gather"
+    # an operand named after a collective does not make a fusion one
+    assert T.op_name("%fusion.3 = bf16[8] fusion(bf16[8] %all-reduce.3)") \
+        == "fusion"
+    assert T.op_name("psum.36") == "psum"
+
+
 def test_busy_union_and_idle_by_hand():
     s = hand()
     busy, window = T.busy_share(s)

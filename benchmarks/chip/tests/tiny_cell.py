@@ -8,6 +8,8 @@ ROOT = os.path.dirname(os.path.dirname(CHIP))
 
 
 def tiny_cell(tp: int = 1, dtype: str = "bfloat16", limit: float = 0.2):
+    """At tp 1 the one-chip chat cell shrunk, comparing ``widest_gap`` at
+    ``limit``; at tp > 1 the four-chip cell shrunk, with its own limits."""
     hq, hkv = (8, 4) if tp > 1 else (4, 2)
     model = {"num_hidden_layers": 2, "hidden_size": 128,
              "intermediate_size": 256, "num_attention_heads": hq,
@@ -29,8 +31,16 @@ def tiny_cell(tp: int = 1, dtype: str = "bfloat16", limit: float = 0.2):
     mix.update(warm_in_s=0.5, drain_cap_s=30, trace_s=1)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
+    # the metrics of the cell this one shrinks
+    like = "mistral-nemo-12b-tp4.chat" if tp > 1 else "stablelm-1.6b.chat"
+    if tp > 1:
+        with open(os.path.join(CHIP, "cells", like + ".json")) as f:
+            limits = json.load(f)["limits"]
+    else:
+        limits = {"widest_gap": limit}
     return {"name": "tiny", "chips": tp, "conf": conf, "mix": mix,
-            "own": {"rate_req_s": 4.0, "limits": {"widest_gap": limit}},
-            "end_to_end": spec["end_to_end"],
+            "own": {"rate_req_s": 4.0, "limits": limits},
+            "end_to_end": [m for m in spec["end_to_end"]
+                           if like in m.get("workloads", [like])],
             "per_layer": [m for m in spec["per_layer"]
-                          if "workloads" not in m]}
+                          if like in m.get("workloads", [like])]}

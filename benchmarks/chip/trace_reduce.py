@@ -39,14 +39,23 @@ SPAN_LABELS = (("bench.prefill", "admit/prefill"),
                ("bench.wait", "waiting for arrivals"))
 
 _OP = re.compile(r"^%?([A-Za-z_][\w\-]*?)(\.\d+)?(\s*=.*)?$", re.S)
+#: a cross-chip collective's operation in an instruction's text
+_COLLECTIVE = re.compile(r"\s((?:all-reduce|all-gather|reduce-scatter|"
+                         r"collective-permute|all-to-all)(?:-start|-done)?)"
+                         r"\(")
 
 
 def op_name(hlo: str) -> str:
     """``%paged_decode_attention.9 = f32[...] custom-call(...)`` ->
-    ``paged_decode_attention``."""
-    head = hlo.split(" = ", 1)[0].strip()
-    m = _OP.match(head)
-    return m.group(1) if m else head
+    ``paged_decode_attention``; a collective by its operation, whatever
+    JAX named it (``%psum.36 = bf16[...] all-reduce(...)`` ->
+    ``all-reduce``)."""
+    head, _, rest = hlo.partition(" = ")
+    c = _COLLECTIVE.search(" " + rest)
+    if c:
+        return c.group(1)
+    m = _OP.match(head.strip())
+    return m.group(1) if m else head.strip()
 
 
 def load(path: str) -> dict:

@@ -37,6 +37,16 @@ def shapes(cfg) -> dict:
     }
 
 
+def abstract(cfg) -> dict:
+    """The pytree ``make`` returns, as shapes and types only."""
+    import jax
+    import jax.numpy as jnp
+
+    dt = jnp.dtype(cfg.dtype)
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s, dt), shapes(cfg),
+                        is_leaf=lambda x: isinstance(x, tuple))
+
+
 def make(cfg, seed: int, shardings=None):
     """The weights for ``cfg`` from ``seed``, on the device (placed per
     ``shardings`` as they are made, where given)."""
