@@ -1,10 +1,14 @@
 """The benchmark's run of one cell, as functions that tests can call.
 
 A cell (an entry of ``BENCHMARK.json``'s ``workloads``) names a
-configuration (``configs/<config>.json``) and a traffic mix
+configuration (the ``file`` of its entry in ``configs``) and a traffic mix
 (``traffic/<traffic>.json``); its own numbers, the fixed arrival rate and
 the limit of each number the correctness check compares, are in
-``cells/<cell>.json``. A run:
+``cells/<cell>.json``. The configuration names its block (``"block"``),
+the module ``blocks/<block>.py`` that knows the architecture: the sizes the
+program is checked against, the weights' layout, the plain reference's
+forward and the operation counts (``blocks/dense.py`` says what each
+gives). A run:
 
 1. makes the weights on the device from the seed and builds the engine the
    way a user does: ``ContinuousBatchingEngine.from_artifact(..., paged=True)``
@@ -24,6 +28,7 @@ the limit of each number the correctness check compares, are in
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import importlib.util
 import json
@@ -65,40 +70,65 @@ def _json(path: str) -> dict:
         return json.load(f)
 
 
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """The Python file ``path``, loaded by its path (a block, a reader)."""
+    name = os.path.splitext(os.path.basename(path))[0].replace(".", "_")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{os.path.basename(os.path.dirname(path))}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_block(conf: dict, where: str):
+    """The block module that the configuration file ``where`` names
+    (``"block": "<name>"``, the file ``blocks/<name>.py``). There is no
+    default: a file that names no block, or one with no file, is refused."""
+    name = conf.get("block")
+    if not name:
+        raise ValueError(f"{where}: names no block (\"block\": \"<name>\" "
+                         f"for {os.path.join(HERE, 'blocks', '<name>.py')})")
+    path = os.path.join(HERE, "blocks", name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"{where}: block {name!r} has no file {path}")
+    return load_module(path)
+
+
 def load_cell(name: str, bench: Optional[str] = None) -> dict:
-    """The cell ``name`` with its configuration, mix and own numbers."""
-    spec = _json(bench or os.path.join(ROOT, "BENCHMARK.json"))
+    """The cell ``name`` with its configuration, block, mix and own
+    numbers. A configuration's ``file`` is relative to ``bench``'s
+    directory."""
+    bench = bench or os.path.join(ROOT, "BENCHMARK.json")
+    spec = _json(bench)
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    conf_path = os.path.join(os.path.dirname(os.path.abspath(bench)),
+                             configs[w["config"]]["file"])
+    conf = _json(conf_path)
     per_layer = [m for m in spec["per_layer"]
                  if name in m.get("workloads", [name])]
     e2e = [m for m in spec["end_to_end"]
            if name in m.get("workloads", [name])]
-    return {"name": name, "chips": w["chips"],
-            "conf": _json(os.path.join(HERE, "configs", w["config"] + ".json")),
+    return {"name": name, "chips": w["chips"], "conf": conf,
+            "block": load_block(conf, conf_path),
             "mix": _json(os.path.join(HERE, "traffic", w["traffic"] + ".json")),
             "own": _json(os.path.join(HERE, "cells", name + ".json")),
             "end_to_end": e2e, "per_layer": per_layer}
 
 
-def model_config(conf: dict):
+def model_config(conf: dict, block):
     """The program's ModelConfig for a configuration file, checked against
-    the file's published sizes (which the reference reads)."""
+    the sizes its block reads from the file's published ones (which the
+    reference reads)."""
     from repro.configs import get_config
 
     cfg = get_config(conf["repro"]["config"]).with_overrides(
         **conf["repro"].get("overrides", {}))
-    m = conf["model"]
-    want = {"n_layers": m["num_hidden_layers"], "d_model": m["hidden_size"],
-            "d_ff": m["intermediate_size"],
-            "n_heads": m["num_attention_heads"],
-            "n_kv_heads": m["num_key_value_heads"],
-            "resolved_head_dim": m["head_dim"],
-            "vocab_size": m["vocab_size"], "rope_theta": m["rope_theta"],
-            "norm_eps": m["rms_norm_eps"], "dtype": m["torch_dtype"],
-            "tie_embeddings": m["tie_word_embeddings"]}
+    want = block.program_sizes(conf["model"])
     bad = {k: (getattr(cfg, k), v) for k, v in want.items()
            if getattr(cfg, k) != v}
     if bad:
@@ -148,8 +178,9 @@ def _engine_kwargs(cell: dict, backend) -> dict:
     return kw
 
 
-def tp_shardings(cfg, conf: dict):
-    """Where the program places each weight when it serves ``conf`` at
+def tp_shardings(abstract, conf: dict):
+    """Where the program places each weight of ``abstract`` (the block's
+    pytree as ``weights.abstract`` gives it) when it serves ``conf`` at
     ``conf["tp"]`` > 1 (its own ``tp_param_specs`` on its own serving
     mesh), so that the weights are made there and no leaf is ever whole on
     one chip; None at tp 1."""
@@ -162,15 +193,18 @@ def tp_shardings(cfg, conf: dict):
     from repro.models.sharding import tp_param_specs
 
     mesh = make_tp_mesh(conf["tp"])
-    specs = tp_param_specs(W.abstract(cfg), mesh, conf["tp_combine"])
+    specs = tp_param_specs(abstract, mesh, conf["tp_combine"])
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
-def reference_shardings(cfg, chips: int):
-    """Where the reference's weights are made at ``chips`` > 1: every
-    stacked leaf split on its layer axis over the cell's chips, the rest
-    on each of them (the reference takes one layer at a time to the first
-    chip); None on one chip."""
+def reference_shardings(abstract, chips: int):
+    """Where the reference's weights of ``abstract`` (the block's pytree
+    as ``weights.abstract`` gives it) are made at ``chips`` > 1: every leaf
+    of a stack (a top-level group, its leaves stacked on the layer axis)
+    split on that axis over the cell's chips, the rest on each of them (the
+    reference takes one layer at a time to the first chip); a stack whose
+    depth the chips do not divide is held whole on each. None on one
+    chip."""
     if chips == 1:
         return None
     import jax
@@ -178,9 +212,9 @@ def reference_shardings(cfg, chips: int):
 
     mesh = Mesh(np.asarray(jax.devices()[:chips]), ("layers",))
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: NamedSharding(mesh, P("layers") if any(
-            getattr(k, "key", None) == "layers" for k in path) else P()),
-        W.abstract(cfg))
+        lambda path, a: NamedSharding(mesh, P("layers") if len(path) > 1
+                                      and a.shape[0] % chips == 0 else P()),
+        abstract)
 
 
 def warm_lengths(mix: dict) -> List[int]:
@@ -211,9 +245,11 @@ def build(cell: dict, seed: int, backend=None, log=print,
 
     from repro.api import ContinuousBatchingEngine, ModelArtifact, VariantSpec
 
-    cfg = model_config(cell["conf"])
+    conf, block = cell["conf"], cell["block"]
+    cfg = model_config(conf, block)
     t = clock()
-    params = W.make(cfg, seed, tp_shardings(cfg, cell["conf"]))
+    params = W.make(block, conf["model"], seed, tp_shardings(
+        W.abstract(block, conf["model"]), conf))
     jax.block_until_ready(params)
     log(f"weights: {cfg.name} from seed {seed} in {clock() - t:.3f} s")
     art = ModelArtifact.create(cfg.name, f"seed{seed}", params, cfg)
@@ -459,16 +495,17 @@ def pick_checked(reqs, run: dict, seed: int, k: int) -> List[int]:
     return sorted([longest] + [int(i) for i in pick])
 
 
-def check(cell: dict, cfg, reqs, served: Dict[int, list], seed: int,
+def check(cell: dict, reqs, served: Dict[int, list], seed: int,
           controls=(), log=print) -> dict:
     """The served tokens of the checked requests against the reference:
     the widest gap by which a served token's reference logit lies below
     the reference's best, and whether each request was served whole."""
-    conf, mix = cell["conf"], cell["mix"]
+    model, block, mix = cell["conf"]["model"], cell["block"], cell["mix"]
     t = clock()
-    params = W.make(cfg, seed, reference_shardings(cfg, cell["chips"]))
+    params = W.make(block, model, seed, reference_shardings(
+        W.abstract(block, model), cell["chips"]))
     seqs = [(reqs[i].prompt, served[i]) for i in sorted(served)]
-    out = R.gaps(params, conf["model"], seqs,
+    out = R.gaps(params, block, model, seqs,
                  pad_to=mix["engine"]["max_len"],
                  read_len=mix["generator"]["output"]["max"],
                  controls=controls)
@@ -501,12 +538,8 @@ def read_metrics(cell: dict, rec: dict) -> Dict[str, dict]:
     """Every per-layer metric of the cell whose reader finds something."""
     out = {}
     for m in cell["per_layer"]:
-        path = os.path.join(HERE, "metrics", m["name"] + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "metric_" + m["name"].replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        v = mod.read(rec)
+        v = load_module(os.path.join(HERE, "metrics", m["name"] + ".py")
+                        ).read(rec)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
@@ -595,7 +628,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         if xp:
             summary = T.load(xp[0])
         shutil.rmtree(tdir, ignore_errors=True)
-    chk = check(cell, cfg, reqs, served, seed, controls, log)
+    chk = check(cell, reqs, served, seed, controls, log)
     # the numbers compared are those the cell's file gives a limit
     limits = own["limits"]
     correct = bool(served) and chk["short"] == 0 and judged(chk, limits)
@@ -612,7 +645,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
         out["metrics"] = {k: {"value": v, "unit": units[k]}
                           for k, v in vals.items() if k in units}
     else:
-        rec = {"model": cell["conf"]["model"], "chips": chips,
+        rec = {"model": cell["conf"]["model"], "block": cell["block"],
+               "chips": chips,
                "kv_bytes": cell["conf"]["kv_dtype_bytes"],
                "peaks": F.peaks(dev.device_kind) if require_tpu else None,
                "trace": summary, "decode_calls": spans.decode_calls,

@@ -1,6 +1,5 @@
 """Model FLOPs of the traced decode steps over their device time, as a
 share of the chips' bf16 peak: the whole decode step's utilization."""
-import flops as F
 import trace_reduce as T
 
 
@@ -11,6 +10,6 @@ def read(rec):
     calls = rec["decode_calls"][:n]
     if secs <= 0 or not calls:
         return None
-    work = sum(F.decode_flops(rec["model"], c) for c in calls)
+    work = sum(rec["block"].decode_flops(rec["model"], c) for c in calls)
     return 100.0 * work / (secs * rec["chips"]
                            * rec["peaks"]["bf16_flops"]) * n / len(calls)

@@ -15,7 +15,8 @@ def read(rec):
         return None
     fl = by = 0.0
     for c in calls:
-        f, b = F.paged_attn_work(rec["model"], c, rec["kv_bytes"])
+        f, b = rec["block"].decode_attn_work(rec["model"], c,
+                                             rec["kv_bytes"])
         fl, by = fl + f, by + b
     best = F.roofline_s(fl, by, rec["peaks"], rec["chips"])
     return 100.0 * best / secs

@@ -1,5 +1,5 @@
-"""Operation and byte counts against hand counts at stablelm-1.6b's
-published widths."""
+"""Operation and byte counts of the dense block (the one stablelm-1.6b's
+configuration names) against hand counts at its published widths."""
 import json
 import os
 import sys
@@ -10,9 +10,13 @@ CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, CHIP)
 
 import flops as F  # noqa: E402
+import harness  # noqa: E402
 
-with open(os.path.join(CHIP, "configs", "stablelm-1.6b.json")) as f:
-    M = json.load(f)["model"]
+CONF = os.path.join(CHIP, "configs", "stablelm-1.6b.json")
+with open(CONF) as f:
+    CONF_JSON = json.load(f)
+M = CONF_JSON["model"]
+B = harness.load_block(CONF_JSON, CONF)
 
 # one layer: q and o 2048 x 2048 each, k and v 2048 x 2048 each (32 kv
 # heads of 64), the SwiGLU 3 x 2048 x 5632
@@ -22,26 +26,26 @@ ATTN = 4 * 24 * 32 * 64          # per token and attended position
 
 
 def test_layer_params():
-    assert F.layer_params(M) == LAYER == 51_380_224
+    assert B.layer_params(M) == LAYER == 51_380_224
     # 24 layers and the head: 1.44 B weights that each token streams
     assert 24 * LAYER + HEAD == 1_233_125_376 + 205_520_896
 
 
 def test_decode_flops():
-    assert F.decode_flops(M, [1]) == 2 * 24 * LAYER + 2 * HEAD + ATTN
-    assert F.decode_flops(M, [100, 300]) == \
+    assert B.decode_flops(M, [1]) == 2 * 24 * LAYER + 2 * HEAD + ATTN
+    assert B.decode_flops(M, [100, 300]) == \
         2 * (2 * 24 * LAYER + 2 * HEAD) + ATTN * 400
-    assert F.decode_flops(M, []) == 0
+    assert B.decode_flops(M, []) == 0
 
 
 def test_prefill_flops():
     n = 512
-    assert F.prefill_flops(M, n) == \
+    assert B.prefill_flops(M, n) == \
         2 * 24 * LAYER * n + ATTN * n * (n + 1) / 2 + 2 * HEAD
 
 
 def test_paged_attn_work():
-    fl, by = F.paged_attn_work(M, [100, 28], kv_bytes=2)
+    fl, by = B.decode_attn_work(M, [100, 28], kv_bytes=2)
     assert fl == ATTN * 128
     # K and V of 128 live positions, 32 kv heads of 64, bf16, per layer;
     # plus each live row's bf16 query and f32 output
@@ -49,7 +53,7 @@ def test_paged_attn_work():
 
 
 def test_flash_prefill_work():
-    fl, by = F.flash_prefill_work(M, 256, kv_bytes=2)
+    fl, by = B.prefill_attn_work(M, 256, kv_bytes=2)
     assert fl == ATTN * 256 * 257 / 2
     assert by == 24 * 256 * 64 * (32 * (2 + 4) + 2 * 32 * 2)
 

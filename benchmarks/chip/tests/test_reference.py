@@ -52,14 +52,13 @@ def test_reference_matches_the_paged_engine_in_float32():
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, 512, (1, 41), dtype=np.int32)   # 2 blocks + 9
     seen, out = serve_logits(cell, seed, prompt, 10)
-    cfg = harness.model_config(cell["conf"])
-    params = W.make(cfg, seed)
+    block, model = cell["block"], cell["conf"]["model"]
+    params = W.make(block, model, seed)
     seq = np.concatenate([prompt[0], np.asarray(out[:-1], np.int32)])
-    dims = R.dims_of(cell["conf"]["model"])
-    x = R.hidden(params, seq, dims)
+    x = block.hidden(params, seq, model)
     rows = np.arange(len(seq), dtype=np.int32)
     ref = np.asarray(R._head(x, params["final_norm"], params["unembed"],
-                             rows, dims[4], None))
+                             rows, model["rms_norm_eps"], None))
     # the prefill's last position (31), the 9-token tail riding decode and
     # the generated tokens: every position the engine computed
     assert sorted(seen) == list(range(31, len(seq)))
@@ -73,20 +72,20 @@ def test_reference_matches_the_paged_engine_in_float32():
 
 def test_gaps_zero_for_reference_tokens_and_controls_read():
     cell = tiny_cell()
-    cfg = harness.model_config(cell["conf"])
-    params = W.make(cfg, 3)
+    block, model = cell["block"], cell["conf"]["model"]
+    params = W.make(block, model, 3)
     rng = np.random.default_rng(1)
     prompt = rng.integers(0, 512, 20, dtype=np.int32)
-    dims = R.dims_of(cell["conf"]["model"])
     # greedy continuation by the reference itself has gap 0 everywhere
     seq = list(prompt)
     for _ in range(6):
-        x = R.hidden(params, np.asarray(seq, np.int32), dims)
+        x = block.hidden(params, np.asarray(seq, np.int32), model)
         lg = R._head(x, params["final_norm"], params["unembed"],
-                     np.asarray([len(seq) - 1], np.int32), dims[4], None)
+                     np.asarray([len(seq) - 1], np.int32),
+                     model["rms_norm_eps"], None)
         seq.append(int(np.asarray(lg)[0].argmax()))
     served = seq[len(prompt):]
-    (g, ctl), = R.gaps(params, cell["conf"]["model"], [(prompt, served)],
+    (g, ctl), = R.gaps(params, block, model, [(prompt, served)],
                        pad_to=32, read_len=8, controls=("fp8", "int8"))
     assert g.shape == (6,) and np.all(g == 0)
     assert set(ctl) == {"fp8", "int8"}

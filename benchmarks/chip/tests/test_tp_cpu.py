@@ -3,8 +3,9 @@ in a child process (``tp_child.py``; in-process tests see one CPU device):
 a tp=2 cell served on ``ref`` (``ref-tp``) comes out correct with its
 weights and pools split over two devices; the weights are the same bit for
 bit however they are placed; the reference reads the same gaps from
-layer-split weights; leaving out the exchange between chips comes out as
-not correct. And ``tp_collective_ms_per_step`` on made-up traces."""
+layer-split weights, also for a block with two stacks; leaving out the
+exchange between chips comes out as not correct. And
+``tp_collective_ms_per_step`` on made-up traces."""
 import json
 import os
 import subprocess
@@ -56,6 +57,20 @@ def test_weights_bit_identical_however_placed(child):
 
 def test_reference_reads_layer_split_weights_alike(child):
     assert child["placements"]["reference_same"] is True
+
+
+def test_two_stack_block_spread_reads_as_whole(child):
+    # a block with a head_layers stack of another feed-forward width ahead
+    # of layers: the same weights however placed, layers split one a
+    # device, the one head layer whole on each, and the reference read a
+    # layer at a time from four devices as from one
+    t = child["two_stack"]
+    assert t["stacks"] == ["head_layers", "layers"] and t["ff"] == [384, 256]
+    assert t["bit_identical"] is True
+    assert t["held"] == {"head_layers": [True, [1] * 4],
+                         "layers": [False, [1] * 4]}
+    assert len(t["gaps"]) == 5 and all(g >= 0 for g in t["gaps"])
+    assert t["reference_same"] is True
 
 
 def test_exchange_between_chips_left_out_is_not_correct(child):

@@ -3,6 +3,8 @@ import copy
 import json
 import os
 
+import harness
+
 CHIP = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(CHIP))
 
@@ -19,7 +21,8 @@ def tiny_cell(tp: int = 1, dtype: str = "bfloat16", limit: float = 0.2):
     over = {"n_layers": 2, "d_model": 128, "d_ff": 256, "n_heads": hq,
             "n_kv_heads": hkv, "head_dim": 16, "vocab_size": 512,
             "norm_eps": 1e-5, "dtype": dtype}
-    conf = {"repro": {"config": "stablelm-1.6b", "overrides": over},
+    conf = {"block": "dense",
+            "repro": {"config": "stablelm-1.6b", "overrides": over},
             "model": model, "chips": tp, "tp": tp,
             "tp_combine": "psum" if tp > 1 else "exact",
             "kv_budget_bytes": 2_000_000, "kv_dtype_bytes": 2}
@@ -38,7 +41,8 @@ def tiny_cell(tp: int = 1, dtype: str = "bfloat16", limit: float = 0.2):
             limits = json.load(f)["limits"]
     else:
         limits = {"widest_gap": limit}
-    return {"name": "tiny", "chips": tp, "conf": conf, "mix": mix,
+    return {"name": "tiny", "chips": tp, "conf": conf,
+            "block": harness.load_block(conf, "tiny_cell"), "mix": mix,
             "own": {"rate_req_s": 4.0, "limits": limits},
             "end_to_end": [m for m in spec["end_to_end"]
                            if like in m.get("workloads", [like])],

@@ -16,6 +16,14 @@ import weights as W  # noqa: E402
 from tiny_cell import tiny_cell  # noqa: E402
 
 SEED = 2**33 + 21
+#: the test-only block with a head_layers stack ahead of layers
+TWO_STACK = os.path.join(CHIP, "tests", "fixtures", "two_stack.py")
+TWO_STACK_MODEL = {"num_hidden_layers": 5, "first_k_dense": 1,
+                   "hidden_size": 128, "intermediate_size": 256,
+                   "dense_intermediate_size": 384, "num_attention_heads": 4,
+                   "num_key_value_heads": 2, "head_dim": 16,
+                   "vocab_size": 512, "rope_theta": 10000.0,
+                   "rms_norm_eps": 1e-5, "torch_dtype": "bfloat16"}
 
 
 def placements(cell) -> dict:
@@ -24,10 +32,13 @@ def placements(cell) -> dict:
     import jax
     import numpy as np
 
-    cfg = harness.model_config(cell["conf"])
-    one = W.make(cfg, SEED)
-    tp = W.make(cfg, SEED, harness.tp_shardings(cfg, cell["conf"]))
-    layer = W.make(cfg, SEED, harness.reference_shardings(cfg, cell["chips"]))
+    block, model = cell["block"], cell["conf"]["model"]
+    abstract = W.abstract(block, model)
+    one = W.make(block, model, SEED)
+    tp = W.make(block, model, SEED,
+                harness.tp_shardings(abstract, cell["conf"]))
+    layer = W.make(block, model, SEED,
+                   harness.reference_shardings(abstract, cell["chips"]))
     same = all(np.array_equal(np.asarray(a), np.asarray(b))
                and np.array_equal(np.asarray(a), np.asarray(c))
                for a, b, c in zip(jax.tree.leaves(one), jax.tree.leaves(tp),
@@ -40,14 +51,51 @@ def placements(cell) -> dict:
     rng = np.random.default_rng(3)
     seqs = [(rng.integers(0, 512, 20, dtype=np.int32),
              rng.integers(0, 512, 5, dtype=np.int32))]
-    model = cell["conf"]["model"]
-    g1 = R.gaps(one, model, seqs, pad_to=32, read_len=8, controls=("fp8",))
-    g2 = R.gaps(layer, model, seqs, pad_to=32, read_len=8,
+    g1 = R.gaps(one, block, model, seqs, pad_to=32, read_len=8,
+                controls=("fp8",))
+    g2 = R.gaps(layer, block, model, seqs, pad_to=32, read_len=8,
                 controls=("fp8",))
     gaps_same = (np.array_equal(g1[0][0], g2[0][0])
                  and np.array_equal(g1[0][1]["fp8"], g2[0][1]["fp8"]))
     return {"bit_identical": bool(same), "devices": devs,
             "reference_same": bool(gaps_same)}
+
+
+def two_stack() -> dict:
+    """The two-stack block's weights made on one device and as the
+    reference takes them over four (``layers`` split on its layer axis,
+    the one-layer ``head_layers`` whole on each), and the reference read
+    whole on one device and a layer at a time from the four."""
+    import jax
+    import numpy as np
+
+    block, model = harness.load_module(TWO_STACK), TWO_STACK_MODEL
+    one = W.make(block, model, SEED)
+    spread = W.make(block, model, SEED, harness.reference_shardings(
+        W.abstract(block, model), 4))
+    same = all(np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(spread)))
+    rng = np.random.default_rng(4)
+    seqs = [(rng.integers(0, 512, 20, dtype=np.int32),
+             rng.integers(0, 512, 5, dtype=np.int32))]
+    g1 = R.gaps(one, block, model, seqs, pad_to=32, read_len=8,
+                controls=("fp8",))
+    g2 = R.gaps(spread, block, model, seqs, pad_to=32, read_len=8,
+                controls=("fp8",))
+
+    def held(a):
+        return [a.sharding.is_fully_replicated,
+                sorted(s.data.shape[0] for s in a.addressable_shards)]
+    return {"stacks": sorted(k for k, v in one.items() if isinstance(v, dict)),
+            "ff": [one[k]["mlp"]["wo"].shape[1]
+                   for k in ("head_layers", "layers")],
+            "bit_identical": bool(same),
+            "held": {k: held(spread[k]["attn"]["wq"])
+                     for k in ("head_layers", "layers")},
+            "gaps": g1[0][0].tolist(),
+            "reference_same": bool(
+                np.array_equal(g1[0][0], g2[0][0])
+                and np.array_equal(g1[0][1]["fp8"], g2[0][1]["fp8"]))}
 
 
 def no_exchange(cell) -> dict:
@@ -100,6 +148,7 @@ def main() -> int:
     cell = tiny_cell(tp=2)
     print(json.dumps({"n_devices": len(jax.devices()),
                       "placements": placements(cell),
+                      "two_stack": two_stack(),
                       "served": served(cell),
                       "no_exchange": no_exchange(cell)}))
     return 0

@@ -1,12 +1,15 @@
 """Random weights from ``--seed``, made on the device in one jitted call, in
-the layout and type the served model takes (the ``repro.models``
-transformer pytree: ``embed``, ``final_norm``, ``unembed`` and stacked
-``layers``). The benchmark makes them, not the program, so the plain
-reference can make the same ones again after the program has been freed.
+the layout and type the served model takes: the pytree of shapes that the
+configuration's block gives (``blocks/<block>.py`` ``shapes``), in the type
+the configuration states (``torch_dtype``). The benchmark makes them, not
+the program, so the plain reference can make the same ones again after the
+program has been freed.
 
-Scales: matrices N(0, 1/fan_in), the embedding N(0, 1), norm weights
-N(0, 0.1^2) around the program's ``x * (1 + w)`` convention, so every
-norm weight matters to the result.
+Scales, by the leaf's name: norm weights (a name holding ``norm`` or
+``ln``) N(0, 0.1^2) around the program's ``x * (1 + w)`` convention, so
+every norm weight matters to the result; the embedding N(0, 1); every other
+matrix N(0, 1/fan_in). Leaf ``i`` in the tree's order draws from
+``fold_in(key, i)``.
 """
 from __future__ import annotations
 
@@ -22,39 +25,25 @@ def jax_seed(seed: int) -> int:
                & 0x7FFFFFFF)
 
 
-def shapes(cfg) -> dict:
-    """Leaf shapes of the pytree ``make`` returns."""
-    d, hd, L = cfg.d_model, cfg.resolved_head_dim, cfg.n_layers
-    hq, hkv, ff, v = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab_size
-    return {
-        "embed": (v, d), "final_norm": (d,), "unembed": (d, v),
-        "layers": {
-            "ln1": (L, d), "ln2": (L, d),
-            "attn": {"wq": (L, d, hq * hd), "wk": (L, d, hkv * hd),
-                     "wv": (L, d, hkv * hd), "wo": (L, hq * hd, d)},
-            "mlp": {"wi": (L, d, 2 * ff), "wo": (L, ff, d)},
-        },
-    }
-
-
-def abstract(cfg) -> dict:
+def abstract(block, model: dict) -> dict:
     """The pytree ``make`` returns, as shapes and types only."""
     import jax
     import jax.numpy as jnp
 
-    dt = jnp.dtype(cfg.dtype)
-    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s, dt), shapes(cfg),
+    dt = jnp.dtype(model["torch_dtype"])
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s, dt),
+                        block.shapes(model),
                         is_leaf=lambda x: isinstance(x, tuple))
 
 
-def make(cfg, seed: int, shardings=None):
-    """The weights for ``cfg`` from ``seed``, on the device (placed per
-    ``shardings`` as they are made, where given)."""
+def make(block, model: dict, seed: int, shardings=None):
+    """The weights of ``block`` at the sizes ``model`` from ``seed``, on the
+    device (placed per ``shardings`` as they are made, where given)."""
     import jax
     import jax.numpy as jnp
 
-    dt = jnp.dtype(cfg.dtype)
-    tree = shapes(cfg)
+    dt = jnp.dtype(model["torch_dtype"])
+    tree = block.shapes(model)
     leaves, treedef = jax.tree.flatten(tree, is_leaf=lambda x: isinstance(
         x, tuple))
     paths = [jax.tree_util.keystr(p) for p, _ in
